@@ -36,6 +36,7 @@ from benchmarks import (
     t9_sensitivity,
     t10_speculative,
 )
+from repro.launch import compile_cache
 
 MODULES = {
     "t1": t1_main,
@@ -73,6 +74,7 @@ def main() -> None:
     )
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else set(MODULES)
+    compile_cache.enable()
 
     print("name,us_per_call,derived")
     failures = 0

@@ -5,9 +5,13 @@ planners, actor) running REAL JAX engines.
     PYTHONPATH=src python examples/serve_agent.py [--n 30] [--env tabmwp]
 
 This is the paper's deployment in miniature: every control-plane LM call is
-executed on a JAX model (reduced configs on CPU; swap --full on TPU), with
-batched continuous decoding inside each engine, and the cache deciding which
-tier serves each request.
+executed on a JAX model, and the cache decides which tier serves each
+request. It calls :func:`repro.launch.serve.serve` on the default
+deployment, with reduced configs unless ``--full`` asks for published
+widths; at those widths the default deployment's large planner
+(nemotron-4-15b, 31.3 GB in bf16) needs more than one TPU v5e. For the
+deployment that fits one chip, run
+``python -m repro.launch.serve --full --deployment one-chip``.
 """
 
 import argparse
@@ -19,14 +23,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import jax
 
-from repro.configs import registry
 from repro.configs.apc_minion import DEFAULT
-from repro.core.agent_loop import AgentConfig, PlanActAgent
-from repro.core.cost_model import CostLedger
-from repro.envs.workloads import get_env
-from repro.models import lm
-from repro.serving.engine import Engine
-from repro.serving.jax_backend import JaxBackend
+from repro.launch.serve import serve
 
 
 def main():
@@ -36,40 +34,15 @@ def main():
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args()
 
-    dep = DEFAULT
-    print(f"tiers: large={dep.large_planner}  small={dep.small_planner}  "
-          f"actor={dep.actor}  (reduced configs, {len(jax.devices())} device)")
-    engines, built = {}, {}
-    for role, arch in (("large_planner", dep.large_planner),
-                       ("small_planner", dep.small_planner),
-                       ("actor", dep.actor),
-                       ("keyword_extractor", dep.keyword_extractor)):
-        if arch not in built:
-            cfg = registry.get(arch) if args.full else registry.get_smoke(arch)
-            params = lm.init_params(cfg, jax.random.PRNGKey(len(built)))
-            built[arch] = Engine(cfg, params, max_len=160)
-        engines[role] = built[arch]
-
-    backend = JaxBackend(engines, seed=0)
-    ledger = CostLedger(pricing_map=dict(dep.pricing))
-    agent = PlanActAgent(backend, ledger, AgentConfig(method="apc"))
-
-    tasks = get_env(args.env).generate(args.n, seed=0)
+    widths = "published widths" if args.full else "reduced configs"
+    print(f"tiers: large={DEFAULT.large_planner}  small={DEFAULT.small_planner}  "
+          f"actor={DEFAULT.actor}  ({widths}, {len(jax.devices())} device)")
     t0 = time.time()
-    ok = hits = 0
-    for i, t in enumerate(tasks):
-        rec = agent.run_task(t)
-        ok += rec.correct
-        hits += rec.hit
-        tag = "HIT " if rec.hit else "MISS"
-        if i < 8 or (i + 1) % 10 == 0:
-            print(f"  [{i+1:3d}] {tag} kw={rec.keyword[:34]:36s} "
-                  f"correct={rec.correct}")
-    print(f"\nn={args.n}  accuracy={ok/args.n:.2f}  hit_rate={hits/args.n:.2f}  "
-          f"cost=${ledger.total_cost():.3f}  wall={time.time()-t0:.1f}s")
-    print("engine tokens served:",
-          {r: e.stats.prefill_tokens + e.stats.decode_tokens
-           for r, e in engines.items()})
+    rep = serve(DEFAULT, env=args.env, n=args.n, full=args.full)
+    print(f"\nn={rep.tasks}  accuracy={rep.accuracy:.2f}  "
+          f"hit_rate={rep.hits / max(1, rep.tasks):.2f}  cost=${rep.cost:.3f}  "
+          f"wall={time.time()-t0:.1f}s")
+    print("engine tokens served:", rep.tokens)
 
 
 if __name__ == "__main__":

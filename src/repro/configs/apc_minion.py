@@ -67,7 +67,19 @@ class APCDeployment:
     index_backend: str = "auto"
 
 
+# DEFAULT does not fit one TPU v5e at published widths: nemotron-4-15b is
+# 31.3 GB in bf16 against the chip's 16 GB of HBM. It needs a large planner
+# split over several chips, which no served path builds yet.
 DEFAULT = APCDeployment()
+
+# The deployment that fits one v5e at published widths: qwen2.5-3b (6.79 GB
+# bf16) plays actor and large planner, olmo-1b (2.35 GB) small planner and
+# keyword extractor. Fuzzy plan-cache matching on the device-resident index.
+ONE_CHIP = APCDeployment(
+    large_planner="qwen2.5-3b",
+    fuzzy_matching=True,
+    index_backend="device",
+)
 
 # Flagship-scale variant: trillion-param MoE as the large planner.
 FLAGSHIP = APCDeployment(large_planner="kimi-k2-1t-a32b")

@@ -1,6 +1,6 @@
 """qwen2.5-3b: dense GQA transformer with QKV bias.
 
-[hf:Qwen/Qwen2.5-0.5B; hf] — 36L d_model=2048 16H (GQA kv=2) d_ff=11008
+[hf:Qwen/Qwen2.5-3B; hf] — 36L d_model=2048 16H (GQA kv=2) d_ff=11008
 vocab=151936, GQA, QKV bias.
 """
 
@@ -20,7 +20,7 @@ CONFIG = ModelConfig(
     mlp_act="swiglu",
     norm_type="rmsnorm",
     rope_theta=1_000_000.0,
-    source="hf:Qwen/Qwen2.5-0.5B",
+    source="hf:Qwen/Qwen2.5-3B",
 )
 
 SHARDING = ShardingProfile(

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed import mesh_compat
 
 
 def pipeline_apply(
@@ -99,7 +98,7 @@ def pipeline_apply(
         outs = jax.lax.psum(outs, axis)
         return outs
 
-    fn = mesh_compat.shard_map(
+    fn = jax.shard_map(
         stage_body,
         mesh=mesh,
         in_specs=(p_spec, P()),

@@ -8,11 +8,10 @@ whose N dimension is streamed block-by-block.
 
 Tiling: grid = (n_q_blocks, n_n_blocks) with the N axis ``arbitrary`` so a
 running top-k can live in VMEM scratch. Each step computes a (bq, bn) score
-tile on the MXU, masks the N-padding tail, concatenates with the carried
-(bq, k) best-so-far and re-selects top-k via ``jax.lax.top_k`` — a k-way
-merge whose cost is O(bq * (k + bn)) on the VPU, negligible next to the
-matmul. Ties resolve to the lowest bank row (carried entries precede the
-current tile, and earlier tiles hold earlier rows).
+tile on the MXU, masks the N-padding tail, merges it with the carried
+(bq, k) best-so-far and re-selects top-k in k rounds of row max, lowest
+row attaining it, and mask — a merge whose cost is O(k * bq * bn) on the
+VPU, small next to the matmul. Ties resolve to the lowest bank row.
 
 On CPU (this container) the kernel runs with ``interpret=True``; on TPU the
 same call sites compile to Mosaic. D must be a multiple of 128 (lane width);
@@ -28,9 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 NEG_INF = -1e30
+_NO_ROW = 2**31 - 1  # larger than any bank row: loses every min
 
 
 def _topk_kernel(q_ref, b_ref, s_out, i_out, s_scr, i_scr, *, block_n, n_total,
@@ -44,17 +42,38 @@ def _topk_kernel(q_ref, b_ref, s_out, i_out, s_scr, i_scr, *, block_n, n_total,
 
     q = q_ref[...].astype(jnp.float32)  # (bq, D)
     b = b_ref[...].astype(jnp.float32)  # (bn, D)
+    # full f32 passes: the device backend must give the same answers as the
+    # exact brute and dense backends (the cost of the extra MXU passes is
+    # not measured)
     s = jax.lax.dot_general(
-        q, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )  # (bq, bn)
     pos = jn * block_n + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(pos >= n_total, NEG_INF, s)
 
-    cat_s = jnp.concatenate([s_scr[...], s], axis=1)  # (bq, k + bn)
-    cat_i = jnp.concatenate([i_scr[...], pos], axis=1)
-    top_s, sel = jax.lax.top_k(cat_s, k)
+    # k-way merge of the carried best-so-far with this tile, in k rounds of
+    # (row max -> lowest bank row attaining it -> mask that row out). Only
+    # max/min reductions and selects: Mosaic lowers no sort or top_k.
+    # Carried rows come from earlier tiles, so "lowest row among ties" is
+    # the same tie rule as a stable sort over the whole bank.
+    c_s, c_i = s_scr[...], i_scr[...]  # (bq, k)
+    col = jax.lax.broadcasted_iota(jnp.int32, c_s.shape, 1)
+    top_s = jnp.full_like(c_s, NEG_INF)
+    top_i = jnp.full_like(c_i, -1)
+    for r in range(k):
+        m = jnp.maximum(jnp.max(c_s, axis=1, keepdims=True),
+                        jnp.max(s, axis=1, keepdims=True))  # (bq, 1)
+        row = jnp.minimum(
+            jnp.min(jnp.where(c_s == m, c_i, _NO_ROW), axis=1, keepdims=True),
+            jnp.min(jnp.where(s == m, pos, _NO_ROW), axis=1, keepdims=True),
+        )
+        top_s = jnp.where(col == r, m, top_s)
+        top_i = jnp.where(col == r, row, top_i)
+        c_s = jnp.where(c_i == row, NEG_INF, c_s)
+        s = jnp.where(pos == row, NEG_INF, s)
     s_scr[...] = top_s
-    i_scr[...] = jnp.take_along_axis(cat_i, sel, axis=1)
+    i_scr[...] = top_i
 
     @pl.when(jn == n_blocks - 1)
     def _finalize():
@@ -117,7 +136,7 @@ def topk_cosine(
             pltpu.VMEM((block_q, k), jnp.float32),
             pltpu.VMEM((block_q, k), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 
 def _ssd_kernel(x_ref, dt_ref, A_ref, B_ref, C_ref, D_ref, o_ref, st_out_ref,
@@ -108,7 +107,7 @@ def ssd(
             jax.ShapeDtypeStruct((Bb, H, P, Ns), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, Ns), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -26,7 +26,6 @@ import jax
 
 from repro.configs import registry
 from repro.configs.base import SHAPES
-from repro.distributed import mesh_compat
 from repro.distributed import sharding as shd
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import DECODE_HEADROOM, input_specs
@@ -189,7 +188,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *, save_hlo: bool = Tru
     try:
         fn, args = build_cell(arch, shape_name, mesh,
                               remat_override=remat_override, variant=variant)
-        with mesh_compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = fn.lower(*args)
             t1 = time.time()
             compiled = lowered.compile()

@@ -4,16 +4,21 @@ Defined as functions (never module-level constants) so importing this module
 never touches jax device state — jax locks the device count on first use,
 and smoke tests must see 1 device while the dry-run sees 512.
 
-Mesh construction goes through ``repro.distributed.mesh_compat`` so the
-same code runs on jax 0.4.37 (this container) and jax>=0.6 (the
-``axis_types`` surface).
+Every axis is ``AxisType.Auto``: the model code places arrays through
+``NamedSharding``/``with_sharding_constraint`` and lets the partitioner
+propagate the rest (``jax.make_mesh`` defaults to ``Explicit`` axes).
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro.distributed import mesh_compat
+
+def make_mesh(shape, axes):
+    """Mesh of ``shape`` over the first devices, all axes Auto."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -21,15 +26,4 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: (pod=2, data=16, model=16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return mesh_compat.make_mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    """Arbitrary mesh (tests / examples)."""
-    return mesh_compat.make_mesh(tuple(shape), tuple(axes))
-
-
-def make_host_mesh():
-    """Whatever devices exist, as a 1-D 'data' mesh (CPU smoke scale)."""
-    n = len(jax.devices())
-    return mesh_compat.make_mesh((n,), ("data",))
+    return make_mesh(shape, axes)

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed import mesh_compat
 from repro.models.common import Params, dense_init, dtype_of, split_keys
 
 
@@ -323,7 +322,7 @@ def moe_forward_ep(
         pspec_params["w_gate"] = P(ep_axis, None, None)
     batch_spec = P(dp_axes if len(dp_axes) > 1 else dp_axes[0], None, None)
 
-    fn = mesh_compat.shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(pspec_params, batch_spec),

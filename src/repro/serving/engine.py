@@ -11,8 +11,10 @@ pool). The engine exposes:
   * ``measured_rates()`` — tokens/s observed, fed into the APC cost model so
     control-plane latency numbers come from the actual data plane
 
-On CPU this runs the reduced configs; on TPU the same code runs the full
-configs under the production mesh (in_shardings from distributed/sharding).
+An engine computes where its parameters live: on the default device, for
+every served path today. ``mesh=`` threads a ``ParallelCtx`` through the
+model code, but no caller passes one, so a model must fit one device
+(``configs/apc_minion.ONE_CHIP`` does on a TPU v5e; ``DEFAULT`` does not).
 """
 
 from __future__ import annotations

@@ -87,9 +87,9 @@ def test_big_matrices_are_sharded():
 
 # -- multi-device subprocess tests ----------------------------------------------
 #
-# These target the jax>=0.6 mesh surface through repro.distributed.mesh_compat
-# (AxisType / set_mesh / shard_map(check_vma=) mapped onto their jax 0.4.37
-# equivalents), so they run on both the pinned 0.4.37 container and newer jax.
+# Each runs in a child process with virtual CPU devices (XLA_FLAGS must be
+# set before jax starts). Meshes come from repro.launch.mesh (Auto axes);
+# set_mesh and shard_map are jax's own.
 
 
 def test_ep_moe_matches_oracle_on_mesh():
@@ -97,7 +97,7 @@ def test_ep_moe_matches_oracle_on_mesh():
         """
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs.base import ModelConfig, MoEConfig
-        from repro.distributed.mesh_compat import make_mesh, set_mesh
+        from repro.launch.mesh import make_mesh
         from repro.models import moe
         cfg = ModelConfig(name='t', family='moe', num_layers=1, d_model=64,
                           num_heads=4, num_kv_heads=2, head_dim=16, d_ff=32,
@@ -110,7 +110,7 @@ def test_ep_moe_matches_oracle_on_mesh():
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 64), jnp.float32)
         y_ref, _ = moe.moe_forward_grouped(cfg, p, x)
         mesh = make_mesh((2, 4), ('data', 'model'))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             y, _ = jax.jit(lambda p, x: moe.moe_forward_ep(
                 cfg, p, x, mesh=mesh, ep_axis='model', dp_axes=('data',)))(p, x)
         err = float(jnp.abs(y - y_ref).max())
@@ -124,14 +124,14 @@ def test_pipeline_parallel_fwd_bwd():
     run_sub(
         """
         import jax, jax.numpy as jnp, numpy as np
-        from repro.distributed.mesh_compat import make_mesh, set_mesh
+        from repro.launch.mesh import make_mesh
         from repro.distributed.pipeline import pipeline_apply, sequential_reference
         mesh = make_mesh((4,), ('pipe',))
         L, D, B = 8, 16, 8
         ws = jax.random.normal(jax.random.PRNGKey(0), (L, D, D)) * 0.1
         layer_fn = lambda w, h: jnp.tanh(h @ w) + h
         x = jax.random.normal(jax.random.PRNGKey(1), (B, D))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             y = jax.jit(lambda ws, x: pipeline_apply(
                 layer_fn, ws, x, mesh=mesh, axis='pipe', n_microbatches=4))(ws, x)
             g = jax.jit(jax.grad(lambda ws: jnp.sum(pipeline_apply(
@@ -155,7 +155,7 @@ def test_sharded_train_step_runs_and_matches_single():
         import jax, jax.numpy as jnp, numpy as np, dataclasses
         from repro.configs import registry
         from repro.distributed import sharding as shd
-        from repro.distributed.mesh_compat import make_mesh, set_mesh
+        from repro.launch.mesh import make_mesh
         from repro.models import lm
         from repro.training.optimizer import AdamWConfig, adamw_init
         from repro.training.train_step import make_train_step
@@ -178,7 +178,7 @@ def test_sharded_train_step_runs_and_matches_single():
         bsh = shd.to_shardings(shd.batch_pspecs(batch, mesh), mesh)
         osh = {'m': psh, 'v': psh,
                'step': shd.to_shardings(jax.sharding.PartitionSpec(), mesh)}
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             step = jax.jit(make_train_step(cfg, opt_cfg, ctx),
                            in_shardings=(psh, osh, bsh))
             p2, o2, m2 = step(params, opt, batch)
@@ -198,7 +198,7 @@ def test_elastic_reshard_preserves_values():
         import jax, jax.numpy as jnp
         from repro.configs import registry
         from repro.distributed.elastic import reshard_tree
-        from repro.distributed.mesh_compat import make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.models import lm
         cfg = registry.get_smoke('qwen2.5-3b')
         profile = registry.get_sharding('qwen2.5-3b')
@@ -220,16 +220,16 @@ def test_compressed_allreduce_on_mesh():
         """
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.distributed.mesh_compat import make_mesh, set_mesh, shard_map
+        from repro.launch.mesh import make_mesh
         from repro.training.grad_compress import compressed_allreduce, ef_state_init
         mesh = make_mesh((8,), ('data',))
         grads = {'w': jnp.arange(8*512, dtype=jnp.float32).reshape(8, 512) / 100}
         ef = ef_state_init({'w': grads['w'][0]})
         def f(g, ef):
             return compressed_allreduce({'w': g}, ef, 'data')
-        fn = shard_map(f, mesh=mesh, in_specs=(P('data', None), P()),
+        fn = jax.shard_map(f, mesh=mesh, in_specs=(P('data', None), P()),
                        out_specs=(P(), P()), check_vma=False)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             out, new_ef = fn(grads['w'], ef)
         ref = np.asarray(grads['w']).mean(0)
         err = float(np.abs(np.asarray(out['w']) - ref).max())

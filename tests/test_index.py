@@ -54,6 +54,30 @@ def test_batch_topk_nonsquare_blocks():
     np.testing.assert_array_equal(np.asarray(i), ri)
 
 
+@pytest.mark.parametrize("k", [3, 8])
+def test_batch_topk_ties_resolve_to_lowest_row(k):
+    """Equal scores pick the lowest bank row, inside one block and across
+    blocks (carried entries vs the current tile). Rows are signed axis
+    vectors or zero (the embedding of an empty text), so every score is
+    exactly -1, 0 or 1 and ties are exact."""
+    from repro.kernels import ops, ref
+
+    n = 700  # six 128-row blocks
+    e = np.eye(DIM, dtype=np.float32)
+    bank = np.tile(-e[0], (n, 1))
+    bank[[5, 40, 130, 300, 699]] = e[0]  # two in block 0, then one each
+    bank[[3, 200, 500, 650]] = 0.0
+    bank[[7, 129, 640]] = e[1]
+    queries = np.stack([e[0], -e[0], e[1], np.zeros(DIM, np.float32)])
+    s, i = ops.batch_topk(queries, bank, k=k, block_q=8, block_n=128)
+    rs, ri = ref.topk_cosine_ref(queries, bank, k)
+    np.testing.assert_array_equal(np.asarray(s), rs)
+    np.testing.assert_array_equal(np.asarray(i), ri)
+    # spelled out: the five +e0 rows, then score-0 rows by row number
+    np.testing.assert_array_equal(
+        np.asarray(i)[0], [5, 40, 130, 300, 699, 3, 7, 129][:k])
+
+
 # -- batched embedding --------------------------------------------------------
 
 

@@ -87,8 +87,8 @@ def test_unrolled_matches_scan_accounting():
 
 
 def test_collective_bytes_from_sharded_fn():
-    # subprocess builds its mesh through repro.distributed.mesh_compat, so
-    # it runs on jax 0.4.37 as well as the jax>=0.6 AxisType surface
+    # a child process: the 8 virtual CPU devices need XLA_FLAGS before
+    # jax starts
     import os
     import subprocess
     import sys
@@ -101,7 +101,7 @@ def test_collective_bytes_from_sharded_fn():
         """
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.distributed.mesh_compat import make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.launch.roofline import HloCostModel
         mesh = make_mesh((8,), ('d',))
         sh = NamedSharding(mesh, P('d', None))
