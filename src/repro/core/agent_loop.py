@@ -14,6 +14,9 @@ strategy: ``run_task`` dispatches to a class registered in
 :mod:`repro.core.methods` — apc, the paper's baselines, and any
 out-of-tree method a scenario registers. There is no per-method branching
 here.
+
+Under a tracer each task is one ``agent.task`` span (``task``, ``method``,
+``hit``), the root of its lookup and role-call spans.
 """
 
 from __future__ import annotations
@@ -27,6 +30,8 @@ from repro.core.cost_model import CostLedger
 from repro.core.journal import StepJournal
 from repro.core.template import ExecutionLog, PlanTemplate, make_template
 from repro.envs.base import Task
+from repro.obs import compile_counter, trace_span
+from repro.obs.names import SPAN_AGENT_TASK
 
 
 @dataclass
@@ -44,6 +49,7 @@ class RunRecord:
     cache_gen_s: float = 0.0
     speculated: bool = False
     spec_outcome: str = ""  # "" | commit | patch | rollback
+    compiles: int = 0  # XLA compiles the process made while the task ran
 
 
 @dataclass
@@ -76,6 +82,7 @@ class PlanActAgent:
         # NB: `cache is not None` — an empty PlanCache is falsy
         self.cache_external = cache is not None
         self.cache: Optional[PlanCache] = cache
+        self._compiles = compile_counter()
         # registry dispatch: the method strategy may SUPPLY self.cache in
         # its setup() (cascade builds an exact->fuzzy->semantic store), so
         # the default store is built only if neither the caller nor the
@@ -121,7 +128,13 @@ class PlanActAgent:
     # ==================================================================
 
     def run_task(self, task: Task) -> RunRecord:
-        return self._method.run(task)
+        n0 = self._compiles.total
+        with trace_span(SPAN_AGENT_TASK, task=task.id,
+                        method=self.cfg.method) as sp:
+            rec = self._method.run(task)
+            sp.set(hit=rec.hit)
+        rec.compiles = self._compiles.total - n0
+        return rec
 
     # ==================================================================
     # inner loops (shared by every method strategy)

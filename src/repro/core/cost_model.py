@@ -1,10 +1,11 @@
 """Token-level cost & latency accounting.
 
 Dollar costs use the paper's Table 8 API prices so benchmark figures stay
-comparable with the paper. Latency uses a serving-rate model: when a JAX
-data plane is attached, rates come from the roofline'd engine; otherwise
-from the published-API throughput defaults below (tokens/s), matching the
-paper's remote-API setting.
+comparable with the paper. Latency uses a serving-rate model: the
+published-API throughput defaults below (tokens/s), matching the paper's
+remote-API setting, or rates a caller passes per role. Nothing feeds it
+the JAX data plane's measured rates: a served path's times are measured
+on the engines themselves (``EngineStats``), not modelled here.
 """
 
 from __future__ import annotations
@@ -16,8 +17,7 @@ from typing import Dict, Optional
 from repro.configs.apc_minion import PAPER_PRICES, TierPricing
 
 # tokens/second + per-call RTT defaults (remote-API regime, calibrated to the
-# paper's Table 3 wall-clock); overridable per role, and replaced by
-# engine-derived rates when a JAX data plane is attached.
+# paper's Table 3 wall-clock); overridable per role.
 DEFAULT_RATES = {
     "large_planner": {"prefill": 5_000.0, "decode": 58.0, "rtt": 0.35},
     "small_planner": {"prefill": 12_000.0, "decode": 110.0, "rtt": 0.30},

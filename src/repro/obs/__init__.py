@@ -13,6 +13,8 @@ Two halves, one package:
   with per-request cache-attribution events
   (:mod:`repro.obs.attribution`). Exporters
   (:mod:`repro.obs.exporters`): canonical JSONL and Chrome-trace format.
+* :mod:`repro.obs.compiles` — the process's XLA compile counter (one
+  ``jax.monitoring`` listener), with a ``jit.compile`` span per compile.
 
 The clock is injectable end to end: under ``repro.sim`` spans bind to the
 ``VirtualClock`` and the exported span stream is byte-deterministic per
@@ -26,6 +28,7 @@ from repro.obs.attribution import (
     deposit,
     tokens_saved_estimate,
 )
+from repro.obs.compiles import CompileCounter, compile_counter
 from repro.obs.exporters import (
     InMemoryExporter,
     JsonlExporter,
@@ -55,6 +58,7 @@ from repro.obs.spans import (
 
 __all__ = [
     "AttributionCollector",
+    "CompileCounter",
     "Counter",
     "Gauge",
     "Histogram",
@@ -68,6 +72,7 @@ __all__ = [
     "Tracer",
     "chrome_trace",
     "collect",
+    "compile_counter",
     "current_span",
     "deposit",
     "get_tracer",

@@ -68,6 +68,9 @@ DEVICE_BATCHED_UPDATES = "index.device.batched_updates"
 DEVICE_CLEARS = "index.device.clears"
 DEVICE_GROWS = "index.device.grows"
 
+JIT_COMPILES = "jit.compiles"
+JIT_COMPILE_S = "jit.compile_s"
+
 METRIC_NAMES = (
     "router.requests",
     "router.hits",
@@ -113,6 +116,8 @@ METRIC_NAMES = (
     "index.device.batched_updates",
     "index.device.clears",
     "index.device.grows",
+    "jit.compiles",
+    "jit.compile_s",
 )
 
 # -- span kinds ------------------------------------------------------------
@@ -133,6 +138,12 @@ SPAN_CACHE_PROMOTE = "cache.promote"
 SPAN_MATCH_STAGE = "match.stage"
 SPAN_INDEX_TOPK = "index.topk"
 SPAN_ENGINE_GENERATE = "engine.generate"
+SPAN_ENGINE_PREFILL = "engine.prefill"
+SPAN_ENGINE_DECODE = "engine.decode"
+SPAN_ENGINE_SAMPLE = "engine.sample"
+SPAN_AGENT_TASK = "agent.task"
+SPAN_BACKEND_CALL = "backend.call"
+SPAN_JIT_COMPILE = "jit.compile"
 
 SPAN_NAMES = (
     "router.route",
@@ -151,6 +162,12 @@ SPAN_NAMES = (
     "match.stage",
     "index.topk",
     "engine.generate",
+    "engine.prefill",
+    "engine.decode",
+    "engine.sample",
+    "agent.task",
+    "backend.call",
+    "jit.compile",
 )
 
 # -- span event kinds ------------------------------------------------------

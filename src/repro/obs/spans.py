@@ -28,7 +28,15 @@ joins the sim's trace-hash determinism contract.
 
 When no tracer is installed, ``trace_span`` hands back a shared no-op
 span: no allocation, no clock read, no lock — instrumentation left in hot
-paths costs one truthiness check.
+paths costs one call into the no-op tracer.
+
+On the host's clock (a ``Tracer`` built without a ``clock``) every
+context-managed span also opens a ``jax.profiler.TraceAnnotation`` of the
+same name and attributes, so a profiler trace holds the program's spans
+natively, beside the device's operations. A tracer on an injected clock
+(the sim's virtual one) opens none. ``Tracer.record_span`` reports work
+that is already over, with the start it had: the compile counter
+(:mod:`repro.obs.compiles`) records each compile that way.
 """
 
 from __future__ import annotations
@@ -45,7 +53,7 @@ class Span:
     once (idempotent ``end``)."""
 
     __slots__ = ("name", "span_id", "parent_id", "start", "end_time",
-                 "attrs", "events", "_tracer", "_token")
+                 "attrs", "events", "_tracer", "_token", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, span_id: int,
                  parent_id: Optional[int], start: float,
@@ -59,6 +67,7 @@ class Span:
         self.events: List[Dict[str, Any]] = []
         self._tracer = tracer
         self._token = None
+        self._annotation = None
 
     def set(self, **attrs: Any) -> "Span":
         self.attrs.update(attrs)
@@ -80,9 +89,16 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._token = _current_span.set(self)
+        annotation = self._tracer.annotation
+        if annotation is not None:
+            self._annotation = annotation(self.name, **self.attrs)
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         if self._token is not None:
             _current_span.reset(self._token)
             self._token = None
@@ -142,12 +158,20 @@ class Tracer:
 
     ``clock`` is any ``() -> float``; production uses the monotonic perf
     counter, ``repro.sim`` passes its :class:`VirtualClock` so span
-    streams are deterministic per seed.
+    streams are deterministic per seed. ``host_clock`` says the default
+    was taken: only then do spans open profiler annotations
+    (``annotation``) and can work timed by the host be placed on it.
     """
 
     def __init__(self, clock: Optional[Callable[[], float]] = None,
                  exporters: Optional[List[Any]] = None):
+        self.host_clock = clock is None
         self.clock = clock if clock is not None else time.perf_counter
+        self.annotation: Optional[Callable[..., Any]] = None
+        if self.host_clock:
+            from jax.profiler import TraceAnnotation
+
+            self.annotation = TraceAnnotation
         self.exporters: List[Any] = list(exporters or [])
         self._lock = threading.Lock()
         self._next_id = 1
@@ -176,6 +200,16 @@ class Tracer:
             pid = parent.span_id
         return Span(self, name, self._alloc_id(), pid, self.clock(), attrs)
 
+    def record_span(self, name: str, start: float, **attrs: Any) -> Span:
+        """A span parented on the current span that began at ``start``
+        (this tracer's clock) and ends now: work reported once it is over.
+        It opens no profiler annotation."""
+        parent = _current_span.get()
+        span = Span(self, name, self._alloc_id(),
+                    None if parent is None else parent.span_id, start, attrs)
+        span.end()
+        return span
+
     def _finish(self, span: Span) -> None:
         with self._lock:
             self.n_spans += 1
@@ -193,6 +227,8 @@ class NoopTracer:
     """Installed by default: every span is the shared no-op span."""
 
     clock = staticmethod(time.perf_counter)
+    host_clock = False
+    annotation = None
     n_spans = 0
 
     def span(self, name: str, **attrs: Any) -> _NoopSpan:
@@ -200,6 +236,9 @@ class NoopTracer:
 
     def start_span(self, name: str, *, parent: Optional[Any] = None,
                    **attrs: Any) -> _NoopSpan:
+        return NOOP_SPAN
+
+    def record_span(self, name: str, start: float, **attrs: Any) -> _NoopSpan:
         return NOOP_SPAN
 
     def close(self) -> None:

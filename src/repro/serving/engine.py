@@ -8,8 +8,17 @@ pool). The engine exposes:
     against a template prefix held in the paged KV pool
     (``serving/kv_cache.py``): a plan-cache hit re-serves a known prefix,
     so only the adaptation prompt pays prefill compute
-  * ``measured_rates()`` — tokens/s observed, fed into the APC cost model so
-    control-plane latency numbers come from the actual data plane
+
+``EngineStats`` sums the host-clock seconds of prefill and decode (each up
+to its logits being ready on the device) and the tokens they processed.
+The APC cost model does not read them: ``CostLedger`` keeps its own rates.
+
+Under a tracer, ``generate`` records ``engine.generate`` (``arch`` is the
+model's name) and beneath it ``engine.prefill`` (``S``, ``path`` =
+``full`` | ``prefix``) and one ``engine.decode`` per step over the same
+intervals as ``EngineStats``, and one ``engine.sample`` per token: from
+logits ready to the next token on the host (the logits' copy to the host,
+``sample_token``, the PRNG split and the EOS mask).
 
 An engine computes where its parameters live: on the default device, for
 every served path today. ``mesh=`` threads a ``ParallelCtx`` through the
@@ -22,7 +31,7 @@ from __future__ import annotations
 import time
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -32,7 +41,12 @@ from repro.configs.base import ModelConfig, ShardingProfile
 from repro.distributed import sharding as shd
 from repro.models import lm
 from repro.obs import trace_span
-from repro.obs.names import SPAN_ENGINE_GENERATE
+from repro.obs.names import (
+    SPAN_ENGINE_DECODE,
+    SPAN_ENGINE_GENERATE,
+    SPAN_ENGINE_PREFILL,
+    SPAN_ENGINE_SAMPLE,
+)
 from repro.serving.kv_cache import CachePoint, KVPrefixCache, PagePoolExhausted
 from repro.serving.sampler import sample_token
 
@@ -48,12 +62,6 @@ class EngineStats:
     prefix_tokens_reused: int = 0
     prefill_s: float = 0.0
     decode_s: float = 0.0
-
-    def rates(self) -> Dict[str, float]:
-        return {
-            "prefill": self.prefill_tokens / self.prefill_s if self.prefill_s else 0.0,
-            "decode": self.decode_tokens / self.decode_s if self.decode_s else 0.0,
-        }
 
 
 class Engine:
@@ -113,29 +121,41 @@ class Engine:
 
         ``n_valid`` is the number of REAL tokens in the batch; without it
         every element counts, padding included — callers that right-pad
-        ragged prompts should pass the true count or the prefill tokens/s
-        rate (and the APC cost model downstream of it) reads high.
+        ragged prompts should pass the true count or the prefill
+        tokens/s rate read from ``EngineStats`` reads high.
         """
-        t0 = time.perf_counter()
-        logits, cache = self._prefill(self.params, {"tokens": jnp.asarray(tokens)})
-        logits.block_until_ready()
-        self.stats.prefill_s += time.perf_counter() - t0
-        self.stats.prefill_tokens += int(tokens.size if n_valid is None else n_valid)
+        logits, cache = self._prefill_on_device(tokens, n_valid)
         return np.asarray(logits), cache
+
+    def _prefill_on_device(self, tokens: np.ndarray,
+                           n_valid: Optional[int]) -> Tuple[Any, Any]:
+        with trace_span(SPAN_ENGINE_PREFILL, S=int(tokens.shape[1]), path="full"):
+            t0 = time.perf_counter()
+            logits, cache = self._prefill(self.params, {"tokens": jnp.asarray(tokens)})
+            logits.block_until_ready()
+            self.stats.prefill_s += time.perf_counter() - t0
+        self.stats.prefill_tokens += int(tokens.size if n_valid is None else n_valid)
+        return logits, cache
 
     def decode(self, cache: Any, tokens: np.ndarray, *,
                active: Optional[int] = None) -> Tuple[np.ndarray, Any]:
         """One decode step. ``active`` counts the rows still generating;
         finished (post-EOS) rows ride along in the dense batch but must
         not inflate the decode tokens/s rate."""
-        t0 = time.perf_counter()
-        logits, cache = self._decode(self.params, cache, jnp.asarray(tokens))
-        logits.block_until_ready()
-        self.stats.decode_s += time.perf_counter() - t0
+        logits, cache = self._decode_on_device(cache, tokens, active)
+        return np.asarray(logits), cache
+
+    def _decode_on_device(self, cache: Any, tokens: np.ndarray,
+                          active: Optional[int]) -> Tuple[Any, Any]:
+        with trace_span(SPAN_ENGINE_DECODE):
+            t0 = time.perf_counter()
+            logits, cache = self._decode(self.params, cache, jnp.asarray(tokens))
+            logits.block_until_ready()
+            self.stats.decode_s += time.perf_counter() - t0
         self.stats.decode_tokens += int(
             tokens.shape[0] if active is None else active
         )
-        return np.asarray(logits), cache
+        return logits, cache
 
     # -- paged KV prefix path ------------------------------------------
 
@@ -173,6 +193,14 @@ class Engine:
         from it — so a mismatched entry (stale registration, re-tokenized
         template) is treated as a miss, never served.
         """
+        res = self._prefill_with_prefix_on_device(
+            template_id, suffix_tokens, n_valid, expected_len)
+        return None if res is None else (np.asarray(res[0]), res[1])
+
+    def _prefill_with_prefix_on_device(
+        self, template_id: str, suffix_tokens: np.ndarray,
+        n_valid: Optional[int], expected_len: Optional[int],
+    ) -> Optional[Tuple[Any, Any]]:
         if self.kv_prefix is None:
             return None
         lease = self.kv_prefix.acquire(template_id)
@@ -186,18 +214,19 @@ class Engine:
         try:
             B, S = suffix_tokens.shape
             pk, pv, plen = self.kv_prefix.gather(lease, batch=B)
-            t0 = time.perf_counter()
-            logits, cache = self._extend(
-                self.params, pk, pv,
-                {"tokens": jnp.asarray(suffix_tokens)}, prefix_len=plen,
-            )
-            logits.block_until_ready()
-            self.stats.prefill_s += time.perf_counter() - t0
+            with trace_span(SPAN_ENGINE_PREFILL, S=S, path="prefix"):
+                t0 = time.perf_counter()
+                logits, cache = self._extend(
+                    self.params, pk, pv,
+                    {"tokens": jnp.asarray(suffix_tokens)}, prefix_len=plen,
+                )
+                logits.block_until_ready()
+                self.stats.prefill_s += time.perf_counter() - t0
             self.stats.prefill_tokens += int(
                 suffix_tokens.size if n_valid is None else n_valid
             )
             self.stats.prefix_tokens_reused += B * plen
-            return np.asarray(logits), cache
+            return logits, cache
         finally:
             self.kv_prefix.release_lease(lease)
 
@@ -232,49 +261,46 @@ class Engine:
                 f"past the cache"
             )
         n_valid = None if prompt_lengths is None else int(np.sum(prompt_lengths))
-        with trace_span(SPAN_ENGINE_GENERATE, batch=B, prompt_len=S,
-                        max_new=max_new) as sp:
+        with trace_span(SPAN_ENGINE_GENERATE, arch=self.cfg.name, batch=B,
+                        prompt_len=S, max_new=max_new) as sp:
             res = None
             if cache_point is not None and self.kv_prefix is not None:
                 suffix = tokens[:, cache_point.prefix_len:]
                 n_suf = (None if n_valid is None
                          else n_valid - B * cache_point.prefix_len)
-                res = self.prefill_with_prefix(
-                    cache_point.template_id, suffix, n_valid=n_suf,
-                    expected_len=cache_point.prefix_len,
+                res = self._prefill_with_prefix_on_device(
+                    cache_point.template_id, suffix, n_suf,
+                    cache_point.prefix_len,
                 )
             if res is None:
-                res = self.prefill(tokens, n_valid=n_valid)
+                res = self._prefill_on_device(tokens, n_valid)
                 if cache_point is not None and self.kv_prefix is not None:
                     self.register_prefix(
                         cache_point.template_id, res[1], cache_point.prefix_len
                     )
             logits, cache = res
             out = []
-            key = jax.random.PRNGKey(seed)
-            tok = sample_token(logits, temperature, key)
+            key = sub = jax.random.PRNGKey(seed)
             done = np.zeros((B,), bool)
             for i in range(max_new):
-                if eos_id is not None and done.any():
-                    # finished rows keep a slot in the dense batch but must
-                    # emit padding, not whatever the sampler drew for them
-                    tok = np.where(done[:, None], pad_id, tok).astype(tok.dtype)
+                with trace_span(SPAN_ENGINE_SAMPLE):
+                    if i:
+                        key, sub = jax.random.split(key)
+                    tok = sample_token(np.asarray(logits), temperature, sub)
+                    if eos_id is not None:
+                        if done.any():
+                            # finished rows keep a slot in the dense batch
+                            # but must emit padding, not whatever the
+                            # sampler drew for them
+                            tok = np.where(done[:, None], pad_id, tok).astype(tok.dtype)
+                        done |= tok[:, 0] == eos_id
                 out.append(tok)
-                if eos_id is not None:
-                    done |= tok[:, 0] == eos_id
-                    if done.all():
-                        break
+                if eos_id is not None and done.all():
+                    break
                 if i + 1 == max_new:
                     break  # the last token is emitted; skip the wasted decode
-                logits, cache = self.decode(
-                    cache, tok, active=int(B - done.sum())
+                logits, cache = self._decode_on_device(
+                    cache, tok, int(B - done.sum())
                 )
-                key, sub = jax.random.split(key)
-                tok = sample_token(logits, temperature, sub)
             sp.set(new_tokens=len(out))
             return np.concatenate(out, axis=1)
-
-    def measured_rates(self) -> Dict[str, float]:
-        r = self.stats.rates()
-        r["rtt"] = 0.0  # local serving: no API round-trip
-        return r

@@ -5,19 +5,23 @@ behavioral layer — random-weight models emit no usable text — while every
 control-plane LM call is *executed* on the data plane with a token count
 matching the call (prefill prompt tokens, decode output tokens). This is the
 standard synthetic-workload methodology: real compute, synthetic content.
-Measured engine rates feed the cost model, replacing the remote-API latency
-defaults.
+The cost model keeps its own rates; the engines' ``EngineStats`` are read
+by whoever measures them. Under a tracer each executed call records
+``backend.call`` (``role``, ``prompt_len``, ``max_new``) around the
+engine's ``engine.generate``.
 """
 
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+from typing import Dict
 
 import numpy as np
 
 from repro.core.backends import SimulatedBackend
 from repro.data.tokenizer import HashTokenizer
 from repro.envs.base import Task
+from repro.obs import trace_span
+from repro.obs.names import SPAN_BACKEND_CALL
 from repro.serving.engine import Engine
 
 
@@ -38,7 +42,10 @@ class JaxBackend(SimulatedBackend):
         if not ids:
             ids = [1]
         arr = np.asarray([ids], np.int32)
-        eng.generate(arr, max_new=min(out_tokens, self.max_exec))
+        max_new = min(out_tokens, self.max_exec)
+        with trace_span(SPAN_BACKEND_CALL, role=role, prompt_len=len(ids),
+                        max_new=max_new):
+            eng.generate(arr, max_new=max_new)
 
     # -- overridden role calls (same returns, + real execution) ----------
 
@@ -65,8 +72,3 @@ class JaxBackend(SimulatedBackend):
         resp, i, o = super().act(task, plan)
         self._exec("actor", plan.text, o)
         return resp, i, o
-
-    def measured_rates(self) -> Dict[str, Dict[str, float]]:
-        return {
-            role: eng.measured_rates() for role, eng in self.engines.items()
-        }

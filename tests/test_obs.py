@@ -9,7 +9,10 @@ Covers the four contracts the observability spine makes:
 * every counter write is thread-safe — concurrent route_batch + async
   cachegen must conserve requests = hits + misses exactly (the seed had a
   data race here: cachegen-pool threads bumped RouterMetrics unlocked);
-* the sim emits byte-identical span streams for identical seeds.
+* the sim emits byte-identical span streams for identical seeds;
+* a served task is one span tree down to each prefill, decode step and
+  sample, the compile counter counts each compile once, and a host-clock
+  tracer's spans appear natively on the profiler's host plane.
 """
 
 import json
@@ -416,3 +419,237 @@ def test_sim_span_stream_is_byte_identical_per_seed(fault):
     other = run_sim(SimConfig(seed=12, scenario="skewed_reuse", fault=fault,
                               n_ops=20))
     assert other.span_digest != a.span_digest
+
+
+# -- serving-path spans and the compile counter ---------------------------------
+
+
+def _smoke_engine(rng_key, max_len=48):
+    from repro.configs import registry
+    from repro.models import lm
+    from repro.serving.engine import Engine
+
+    cfg = registry.get_smoke("olmo-1b")
+    return Engine(cfg, lm.init_params(cfg, rng_key), max_len=max_len)
+
+
+def test_engine_generate_spans_prefill_each_decode_step_and_sample(rng_key):
+    eng = _smoke_engine(rng_key)
+    toks = np.random.RandomState(0).randint(3, 400, (2, 10)).astype(np.int32)
+    eng.generate(toks, max_new=2)  # compiles outside the traced call
+    mem = InMemoryExporter()
+    d0 = eng.stats.decode_s
+    max_new = 5
+    with use_tracer(Tracer(exporters=[mem])):
+        out = eng.generate(toks, max_new=max_new)
+    assert out.shape == (2, max_new)
+    by_name = {}
+    for s in mem.spans:
+        by_name.setdefault(s["name"], []).append(s)
+    (gen,) = by_name[N.SPAN_ENGINE_GENERATE]
+    assert gen["attrs"]["arch"] == eng.cfg.name
+    assert len(by_name[N.SPAN_ENGINE_PREFILL]) == 1
+    assert by_name[N.SPAN_ENGINE_PREFILL][0]["attrs"] == {"S": 10, "path": "full"}
+    assert len(by_name[N.SPAN_ENGINE_DECODE]) == max_new - 1
+    assert len(by_name[N.SPAN_ENGINE_SAMPLE]) == max_new
+    children = [s for s in mem.spans if s is not gen]
+    assert all(s["parent_id"] == gen["span_id"] for s in children), children
+    # per-step spans carry nothing but their timing
+    assert all(s["attrs"] == {} for s in by_name[N.SPAN_ENGINE_DECODE]
+               + by_name[N.SPAN_ENGINE_SAMPLE])
+    # the decode spans close round the very intervals EngineStats sums
+    spans_s = sum(s["end"] - s["start"] for s in by_name[N.SPAN_ENGINE_DECODE])
+    stats_s = eng.stats.decode_s - d0
+    assert stats_s <= spans_s <= stats_s + 0.05 * stats_s + 2e-3
+    # steps follow each other: prefill, then sample and decode in turn
+    order = [s["name"] for s in sorted(children, key=lambda s: s["start"])]
+    assert order == ([N.SPAN_ENGINE_PREFILL]
+                     + [N.SPAN_ENGINE_SAMPLE, N.SPAN_ENGINE_DECODE] * (max_new - 1)
+                     + [N.SPAN_ENGINE_SAMPLE])
+
+
+def test_noop_tracer_records_nothing_and_allocates_no_span(rng_key, monkeypatch):
+    from repro.obs import spans as spans_mod
+
+    eng = _smoke_engine(rng_key)
+    toks = np.random.RandomState(0).randint(3, 400, (1, 8)).astype(np.int32)
+
+    class NoSpan:
+        def __init__(self, *a, **kw):
+            raise AssertionError("a span was allocated with tracing off")
+
+    monkeypatch.setattr(spans_mod, "Span", NoSpan)
+    assert get_tracer().n_spans == 0
+    out = eng.generate(toks, max_new=4)
+    assert out.shape == (1, 4)
+    assert get_tracer().n_spans == 0
+
+
+def test_one_task_is_one_span_tree(rng_key):
+    from repro.configs.apc_minion import DEFAULT
+    from repro.core.agent_loop import AgentConfig, PlanActAgent
+    from repro.core.cost_model import CostLedger
+    from repro.envs.workloads import get_env
+    from repro.serving.jax_backend import JaxBackend
+
+    eng = _smoke_engine(rng_key, max_len=96)
+    engines = {r: eng for r in
+               ("large_planner", "small_planner", "actor", "keyword_extractor")}
+    agent = PlanActAgent(JaxBackend(engines, seed=0, max_exec_tokens=3),
+                         CostLedger(pricing_map=dict(DEFAULT.pricing)),
+                         AgentConfig(method="apc"))
+    (task,) = get_env("tabmwp").generate(1, seed=0)
+    mem = InMemoryExporter()
+    with use_tracer(Tracer(exporters=[mem])):
+        rec = agent.run_task(task)
+    by_id = {s["span_id"]: s for s in mem.spans}
+    (root,) = [s for s in mem.spans if s["parent_id"] is None]
+    assert root["name"] == N.SPAN_AGENT_TASK
+    assert root["attrs"] == {"task": task.id, "method": "apc", "hit": rec.hit}
+
+    def chain(s):
+        names = [s["name"]]
+        while s["parent_id"] is not None:
+            s = by_id[s["parent_id"]]
+            names.append(s["name"])
+        return names[::-1]
+
+    calls = [s for s in mem.spans if s["name"] == N.SPAN_BACKEND_CALL]
+    assert calls and all(s["parent_id"] == root["span_id"] for s in calls)
+    assert {s["attrs"]["role"] for s in calls} >= {"keyword_extractor", "actor"}
+    for s in mem.spans:
+        if s["name"] in (N.SPAN_ENGINE_PREFILL, N.SPAN_ENGINE_DECODE,
+                         N.SPAN_ENGINE_SAMPLE):
+            assert chain(s)[:3] == [N.SPAN_AGENT_TASK, N.SPAN_BACKEND_CALL,
+                                    N.SPAN_ENGINE_GENERATE], chain(s)
+        if s["name"] == N.SPAN_CACHE_LOOKUP:
+            assert chain(s)[0] == N.SPAN_AGENT_TASK
+    assert {s["name"] for s in mem.spans} <= set(N.SPAN_NAMES)
+
+
+def test_compile_counter_counts_a_fresh_shape_once(rng_key):
+    import jax
+
+    from repro.core.agent_loop import AgentConfig, PlanActAgent
+    from repro.core.backends import SimulatedBackend
+    from repro.core.cost_model import CostLedger
+    from repro.configs.apc_minion import DEFAULT
+    from repro.envs.workloads import get_env
+    from repro.obs import compile_counter
+
+    counter = compile_counter()
+    # one counter and one listener: a second listener would count each
+    # compile below twice
+    assert compile_counter() is counter
+
+    def triple(x):
+        return x * 3 + 1
+
+    f = jax.jit(triple)
+    n0 = counter.total
+    series = counter.registry.counter(N.JIT_COMPILES, program="jit(triple)")
+    mem = InMemoryExporter()
+    with use_tracer(Tracer(exporters=[mem])):
+        with trace_span("outer") as outer:
+            f(np.ones(5, np.float32))
+    assert counter.total - n0 == 1 and series.value == 1
+    (comp,) = [s for s in mem.spans if s["name"] == N.SPAN_JIT_COMPILE]
+    assert comp["attrs"] == {"program": "jit(triple)"}
+    assert comp["parent_id"] == outer.span_id
+    assert outer.start <= comp["start"] <= comp["end"] <= outer.end_time
+    seconds = counter.registry.counter(N.JIT_COMPILE_S, program="jit(triple)").value
+    assert seconds == pytest.approx(comp["end"] - comp["start"], abs=1e-3)
+    f(np.ones(5, np.float32))  # a repeat compiles nothing
+    assert counter.total - n0 == 1 and series.value == 1
+    f(np.ones(6, np.float32))  # a new shape compiles once more
+    assert counter.total - n0 == 2 and series.value == 2
+
+    class CompilingBackend(SimulatedBackend):
+        """Extracting a keyword runs ``triple`` at the query's length."""
+
+        def extract_keyword(self, task):
+            f(np.ones(len(task.query), np.float32))
+            return super().extract_keyword(task)
+
+    cfg = AgentConfig(method="apc", index_backend="brute")
+    agent = PlanActAgent(CompilingBackend(seed=0),
+                         CostLedger(pricing_map=dict(DEFAULT.pricing)), cfg)
+    (task,) = get_env("tabmwp").generate(1, seed=3)
+    assert len(task.query) not in (5, 6)
+    n1 = counter.total
+    first = agent.run_task(task)
+    assert first.compiles == counter.total - n1 >= 1
+    assert agent.run_task(task).compiles == 0
+
+
+def test_compile_span_needs_the_host_clock():
+    import jax
+
+    from repro.obs import compile_counter
+
+    counter = compile_counter()
+    mem = InMemoryExporter()
+    fake = {"t": 0.0}
+
+    def clock():
+        fake["t"] += 1.0
+        return fake["t"]
+
+    f = jax.jit(lambda x: x - 7)
+    n0 = counter.total
+    with use_tracer(Tracer(clock=clock, exporters=[mem])):
+        with trace_span("outer"):
+            f(np.ones(3, np.float32))
+    assert counter.total - n0 == 1
+    assert [s["name"] for s in mem.spans] == ["outer"]
+
+
+def test_program_spans_sit_on_the_profilers_host_plane(rng_key, tmp_path):
+    """Under ``jax.profiler`` each span of a host-clock tracer is a host
+    event of the same name, nested as the spans are; a tracer on another
+    clock leaves no event."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    eng = _smoke_engine(rng_key)
+    toks = np.random.RandomState(0).randint(3, 400, (1, 8)).astype(np.int32)
+    eng.generate(toks, max_new=3)
+    mem = InMemoryExporter()
+    fake = {"t": 0.0}
+
+    def clock():
+        fake["t"] += 1.0
+        return fake["t"]
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with use_tracer(Tracer(exporters=[mem])):
+            with trace_span(N.SPAN_AGENT_TASK, task="t0"):
+                eng.generate(toks, max_new=3)
+        with use_tracer(Tracer(clock=clock)):
+            with trace_span(N.SPAN_BACKEND_CALL, role="virtual"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {s["name"] for s in mem.spans}
+    native = [e for plane in ProfileData.from_file(path).planes
+              if plane.name == "/host:CPU" for line in plane.lines
+              for e in line.events if e.name in names | {N.SPAN_BACKEND_CALL}]
+    assert sorted(e.name for e in native) == sorted(s["name"] for s in mem.spans)
+
+    def within(inner, outer):
+        return (outer.start_ns <= inner.start_ns
+                and inner.start_ns + inner.duration_ns <= outer.start_ns + outer.duration_ns)
+
+    (task,) = [e for e in native if e.name == N.SPAN_AGENT_TASK]
+    (gen,) = [e for e in native if e.name == N.SPAN_ENGINE_GENERATE]
+    assert dict(task.stats) == {"task": "t0"}
+    assert dict(gen.stats)["arch"] == eng.cfg.name
+    assert within(gen, task)
+    steps = [e for e in native if e.name in (N.SPAN_ENGINE_PREFILL, N.SPAN_ENGINE_DECODE,
+                                             N.SPAN_ENGINE_SAMPLE)]
+    assert len(steps) == 1 + 2 + 3
+    assert all(within(e, gen) for e in steps)

@@ -14,14 +14,19 @@ from repro.serving.scheduler import ContinuousBatcher, Request, SchedulerConfig
 
 
 def test_engine_generate_and_rates(rng_key):
+    """``EngineStats`` counts every prompt token once and one token per row
+    for each decode step (the last token needs no step), with time on both."""
     cfg = registry.get_smoke("olmo-1b")
     params = lm.init_params(cfg, rng_key)
     eng = Engine(cfg, params, max_len=48)
     toks = np.random.RandomState(0).randint(3, 400, (3, 12)).astype(np.int32)
     out = eng.generate(toks, max_new=6)
     assert out.shape == (3, 6)
-    r = eng.measured_rates()
-    assert r["prefill"] > 0 and r["decode"] > 0
+    st = eng.stats
+    assert st.prefill_tokens == 3 * 12
+    assert st.decode_tokens == 3 * 5
+    assert st.prefill_s > 0 and st.decode_s > 0
+    assert st.prefix_tokens_reused == 0
 
 
 def test_engine_greedy_deterministic(rng_key):
@@ -79,7 +84,8 @@ def test_engine_generate_rejects_over_capacity(rng_key):
 
 def test_engine_prefill_counts_only_valid_tokens(rng_key):
     """prefill_tokens must reflect real tokens, not the padded (B, S)
-    rectangle, or measured_rates() overstates prefill throughput."""
+    rectangle, or a prefill rate read from ``EngineStats`` overstates
+    prefill throughput."""
     cfg = registry.get_smoke("olmo-1b")
     params = lm.init_params(cfg, rng_key)
     eng = Engine(cfg, params, max_len=48)
