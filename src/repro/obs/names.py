@@ -71,6 +71,8 @@ DEVICE_GROWS = "index.device.grows"
 JIT_COMPILES = "jit.compiles"
 JIT_COMPILE_S = "jit.compile_s"
 
+ENGINE_HOST_SYNCS = "engine.host_syncs"
+
 METRIC_NAMES = (
     "router.requests",
     "router.hits",
@@ -118,6 +120,7 @@ METRIC_NAMES = (
     "index.device.grows",
     "jit.compiles",
     "jit.compile_s",
+    "engine.host_syncs",
 )
 
 # -- span kinds ------------------------------------------------------------

@@ -9,16 +9,32 @@ pool). The engine exposes:
     (``serving/kv_cache.py``): a plan-cache hit re-serves a known prefix,
     so only the adaptation prompt pays prefill compute
 
-``EngineStats`` sums the host-clock seconds of prefill and decode (each up
-to its logits being ready on the device) and the tokens they processed.
-The APC cost model does not read them: ``CostLedger`` keeps its own rates.
+``generate`` keeps each sampled token on the device, where it feeds the
+next decode step, so the host does not wait for the device between steps.
+Without ``eos_id`` a call makes three blocking reads: the prefill's logits,
+the first decode step's logits and, after the last step, every token at
+once. The first step is awaited so that a profiler can tie the decode
+program's device time to the call: the TPU runtime records a run's
+completion on the host only where the host awaits the run's output before
+another program takes it in. With ``eos_id`` the host also reads each
+token as it is drawn, to pad finished rows and count the rows still
+active. The counter ``engine.host_syncs`` (in ``Engine.obs``) counts the
+engine's blocking reads.
+
+``EngineStats`` sums the host-clock seconds of prefill (up to its logits
+being ready on the device) and of each call's decode phase (from the first
+step's dispatch to the tokens being on the host), and the tokens they
+processed. The APC cost model does not read them: ``CostLedger`` keeps its
+own rates.
 
 Under a tracer, ``generate`` records ``engine.generate`` (``arch`` is the
 model's name) and beneath it ``engine.prefill`` (``S``, ``path`` =
-``full`` | ``prefix``) and one ``engine.decode`` per step over the same
-intervals as ``EngineStats``, and one ``engine.sample`` per token: from
-logits ready to the next token on the host (the logits' copy to the host,
-``sample_token``, the PRNG split and the EOS mask).
+``full`` | ``prefix``) over the interval ``prefill_s`` sums, one
+``engine.decode`` per step and one ``engine.sample`` per token. The two
+per-step spans cover the host's dispatch of the step and of the sampler
+(with the PRNG split, and the token's read and the EOS mask where
+``eos_id`` is given; the first step's span runs on to its logits being
+ready); they lie inside the interval ``decode_s`` sums.
 
 An engine computes where its parameters live: on the default device, for
 every served path today. ``mesh=`` threads a ``ParallelCtx`` through the
@@ -31,7 +47,7 @@ from __future__ import annotations
 import time
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -40,8 +56,9 @@ import numpy as np
 from repro.configs.base import ModelConfig, ShardingProfile
 from repro.distributed import sharding as shd
 from repro.models import lm
-from repro.obs import trace_span
+from repro.obs import MetricsRegistry, trace_span
 from repro.obs.names import (
+    ENGINE_HOST_SYNCS,
     SPAN_ENGINE_DECODE,
     SPAN_ENGINE_GENERATE,
     SPAN_ENGINE_PREFILL,
@@ -75,12 +92,16 @@ class Engine:
         max_len: int = 512,
         donate_cache: bool = True,
         kv_prefix: Optional[KVPrefixCache] = None,
+        obs: Optional[MetricsRegistry] = None,
+        obs_labels: Optional[Dict[str, str]] = None,
     ):
         self.cfg = cfg
         self.params = params
         self.mesh = mesh
         self.max_len = max_len
         self.stats = EngineStats()
+        self.obs = obs if obs is not None else MetricsRegistry()
+        self._host_syncs = self.obs.counter(ENGINE_HOST_SYNCS, **(obs_labels or {}))
         self.kv_prefix = kv_prefix if cfg.family in _PREFIX_FAMILIES else None
         ctx = None
         if mesh is not None:
@@ -132,30 +153,36 @@ class Engine:
         with trace_span(SPAN_ENGINE_PREFILL, S=int(tokens.shape[1]), path="full"):
             t0 = time.perf_counter()
             logits, cache = self._prefill(self.params, {"tokens": jnp.asarray(tokens)})
-            logits.block_until_ready()
+            self._wait(logits)
             self.stats.prefill_s += time.perf_counter() - t0
         self.stats.prefill_tokens += int(tokens.size if n_valid is None else n_valid)
         return logits, cache
 
     def decode(self, cache: Any, tokens: np.ndarray, *,
                active: Optional[int] = None) -> Tuple[np.ndarray, Any]:
-        """One decode step. ``active`` counts the rows still generating;
-        finished (post-EOS) rows ride along in the dense batch but must
-        not inflate the decode tokens/s rate."""
-        logits, cache = self._decode_on_device(cache, tokens, active)
-        return np.asarray(logits), cache
-
-    def _decode_on_device(self, cache: Any, tokens: np.ndarray,
-                          active: Optional[int]) -> Tuple[Any, Any]:
+        """One decode step, its logits read to the host. ``active`` counts
+        the rows still generating; finished (post-EOS) rows ride along in
+        the dense batch but must not inflate the decode tokens/s rate."""
         with trace_span(SPAN_ENGINE_DECODE):
             t0 = time.perf_counter()
             logits, cache = self._decode(self.params, cache, jnp.asarray(tokens))
-            logits.block_until_ready()
+            logits = self._read(logits)
             self.stats.decode_s += time.perf_counter() - t0
         self.stats.decode_tokens += int(
             tokens.shape[0] if active is None else active
         )
         return logits, cache
+
+    def _wait(self, x: Any) -> None:
+        """Block until ``x`` is computed: one of the engine's host syncs."""
+        self._host_syncs.inc()
+        jax.block_until_ready(x)
+
+    def _read(self, x: Any) -> Any:
+        """``x`` (an array or a list of them) copied to the host: one of the
+        engine's host syncs."""
+        self._host_syncs.inc()
+        return jax.device_get(x)
 
     # -- paged KV prefix path ------------------------------------------
 
@@ -220,7 +247,7 @@ class Engine:
                     self.params, pk, pv,
                     {"tokens": jnp.asarray(suffix_tokens)}, prefix_len=plen,
                 )
-                logits.block_until_ready()
+                self._wait(logits)
                 self.stats.prefill_s += time.perf_counter() - t0
             self.stats.prefill_tokens += int(
                 suffix_tokens.size if n_valid is None else n_valid
@@ -280,14 +307,18 @@ class Engine:
                     )
             logits, cache = res
             out = []
-            key = sub = jax.random.PRNGKey(seed)
+            # greedy draws ignore the key: only a temperature needs the
+            # split sequence
+            key = sub = jax.random.PRNGKey(seed) if temperature > 0.0 else None
             done = np.zeros((B,), bool)
+            t0 = None
             for i in range(max_new):
                 with trace_span(SPAN_ENGINE_SAMPLE):
-                    if i:
+                    if i and key is not None:
                         key, sub = jax.random.split(key)
-                    tok = sample_token(np.asarray(logits), temperature, sub)
+                    tok = sample_token(logits, temperature, sub)
                     if eos_id is not None:
+                        tok = self._read(tok)
                         if done.any():
                             # finished rows keep a slot in the dense batch
                             # but must emit padding, not whatever the
@@ -299,8 +330,20 @@ class Engine:
                     break
                 if i + 1 == max_new:
                     break  # the last token is emitted; skip the wasted decode
-                logits, cache = self._decode_on_device(
-                    cache, tok, int(B - done.sum())
-                )
+                with trace_span(SPAN_ENGINE_DECODE):
+                    if t0 is None:
+                        t0 = time.perf_counter()
+                    # the token goes straight back in: on the device unless
+                    # the host read it for the EOS mask
+                    logits, cache = self._decode(self.params, cache, tok)
+                    if i == 0:
+                        # one round trip a call, so the runtime records the
+                        # first step's completion (see the module docstring)
+                        self._wait(logits)
+                self.stats.decode_tokens += int(B - done.sum())
+            if eos_id is None:
+                out = self._read(out)
+            if t0 is not None:
+                self.stats.decode_s += time.perf_counter() - t0
             sp.set(new_tokens=len(out))
             return np.concatenate(out, axis=1)

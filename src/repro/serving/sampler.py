@@ -2,16 +2,18 @@
 
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 
-def sample_token(
-    logits: np.ndarray, temperature: float = 0.0, key=None, top_k: int = 0
-) -> np.ndarray:
-    """logits: (B, V) -> (B, 1) int32."""
-    lg = jnp.asarray(logits, jnp.float32)
+@partial(jax.jit, static_argnames=("temperature", "top_k"))
+def sample_token(logits, temperature: float = 0.0, key=None, top_k: int = 0):
+    """logits: (B, V) -> (B, 1) int32, on the device: nothing is copied to
+    the host, so the token can feed the next decode step directly. One
+    program per (logits shape, dtype, ``temperature``, ``top_k``)."""
+    lg = logits.astype(jnp.float32)
     if temperature <= 0.0:
         tok = jnp.argmax(lg, axis=-1)
     else:
@@ -21,4 +23,4 @@ def sample_token(
             lg = jnp.where(lg < kth, -jnp.inf, lg)
         key = key if key is not None else jax.random.PRNGKey(0)
         tok = jax.random.categorical(key, lg, axis=-1)
-    return np.asarray(tok[:, None].astype(jnp.int32))
+    return tok[:, None].astype(jnp.int32)

@@ -4,12 +4,16 @@ import threading
 
 import jax
 import numpy as np
+import pytest
 
 from repro.configs import registry
 from repro.core.cache import PlanCache
 from repro.models import lm
+from repro.obs import MetricsRegistry
+from repro.obs import names as N
 from repro.serving.engine import Engine
 from repro.serving.router import TierPool, TwoTierRouter
+from repro.serving.sampler import sample_token
 from repro.serving.scheduler import ContinuousBatcher, Request, SchedulerConfig
 
 
@@ -96,6 +100,76 @@ def test_engine_prefill_counts_only_valid_tokens(rng_key):
     assert eng.stats.prefill_tokens == 26
     eng.generate(toks, max_new=4, prompt_lengths=lengths)
     assert eng.stats.prefill_tokens == 52
+
+
+def _host_loop(eng, toks, max_new, temperature, seed):
+    """Generation as a per-step host loop: each step's logits read to the
+    host, a token drawn from them and handed back to the next step."""
+    logits, cache = eng.prefill(toks)
+    key = sub = jax.random.PRNGKey(seed)
+    out = []
+    for i in range(max_new):
+        if i:
+            key, sub = jax.random.split(key)
+        tok = np.asarray(sample_token(logits, temperature, sub))
+        out.append(tok)
+        if i + 1 < max_new:
+            logits, cache = eng.decode(cache, tok)
+    return np.concatenate(out, axis=1)
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "temperature"])
+@pytest.mark.parametrize("arch", ["olmo-1b", "rwkv6-3b"])
+def test_generate_keeps_tokens_on_device_and_matches_host_loop(arch, temperature,
+                                                               rng_key):
+    """Without ``eos_id`` the tokens stay on the device between steps: a
+    call waits on the device three times (the prefill, the first decode
+    step, the final fetch) and serves exactly the tokens of the per-step
+    host loop, seed for seed."""
+    cfg = registry.get_smoke(arch)
+    eng = Engine(cfg, lm.init_params(cfg, rng_key), max_len=48)
+    toks = np.random.RandomState(2).randint(3, 400, (2, 9)).astype(np.int32)
+    syncs = eng.obs.counter(N.ENGINE_HOST_SYNCS)
+    for seed in (5, 6):
+        s0 = syncs.value
+        got = eng.generate(toks, max_new=7, temperature=temperature, seed=seed)
+        assert syncs.value - s0 == 3
+        assert got.shape == (2, 7) and got.dtype == np.int32
+        np.testing.assert_array_equal(got, _host_loop(eng, toks, 7, temperature, seed))
+
+
+def test_generate_with_eos_reads_each_token(rng_key):
+    """With ``eos_id`` the host reads every token as it is drawn (one sync
+    each, beside the prefill's and the first decode step's) and serves the
+    device loop's tokens up to the first EOS of each row."""
+    cfg = registry.get_smoke("olmo-1b")
+    eng = Engine(cfg, lm.init_params(cfg, rng_key), max_len=48)
+    toks = np.random.RandomState(0).randint(3, 400, (3, 12)).astype(np.int32)
+    free = eng.generate(toks, max_new=6)
+    eos = int(free[0, 2])
+    syncs = eng.obs.counter(N.ENGINE_HOST_SYNCS)
+    s0 = syncs.value
+    out = eng.generate(toks, max_new=6, eos_id=eos, pad_id=1)
+    assert out.shape[1] > 1
+    assert syncs.value - s0 == 2 + out.shape[1]
+    for r in range(out.shape[0]):
+        hits = np.where(free[r] == eos)[0]
+        stop = hits[0] + 1 if hits.size else out.shape[1]
+        np.testing.assert_array_equal(out[r, :stop], free[r, :stop])
+
+
+@pytest.mark.parametrize("max_new,syncs", [(1, 2), (2, 3), (5, 3)])
+def test_engine_host_syncs_land_in_the_given_registry(max_new, syncs, rng_key):
+    """An engine given a registry counts its waits there, under its labels:
+    a single-token call has no decode step to await."""
+    cfg = registry.get_smoke("olmo-1b")
+    reg = MetricsRegistry()
+    eng = Engine(cfg, lm.init_params(cfg, rng_key), max_len=32, obs=reg,
+                 obs_labels={"arch": cfg.name})
+    toks = np.random.RandomState(1).randint(3, 400, (1, 6)).astype(np.int32)
+    assert eng.generate(toks, max_new=max_new).shape == (1, max_new)
+    assert eng.obs is reg
+    assert reg.counter(N.ENGINE_HOST_SYNCS, arch=cfg.name).value == syncs
 
 
 # -- continuous batching -------------------------------------------------------
