@@ -36,6 +36,49 @@ class MoEConfig:
     # EP-path perf knobs (hillclimb; see EXPERIMENTS.md §Perf):
     a2a_dtype: str = "auto"  # auto=x dtype | bfloat16 | float8_e4m3fn
     dispatch_chunks: int = 1  # split tokens into chunks: buffers / chunks
+    # DeepSeekMoE (arXiv:2405.04434): shared experts that every token passes
+    # through, applied as one MLP of num_shared_experts * d_ff_expert
+    num_shared_experts: int = 0
+    norm_topk_prob: bool = True  # renormalise the top-k weights to sum to 1
+    first_k_dense: int = 0  # leading layers with a dense MLP of width d_ff
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434).
+
+    Each position caches a normed latent of ``kv_lora_rank`` values and one
+    rotary key of ``qk_rope_head_dim`` shared by every head; per-head keys
+    (``qk_nope_head_dim`` + the rotary part) and values (``v_head_dim``)
+    are expanded from the latent. Queries are projected directly (no
+    low-rank query projection, as DeepSeek-V2-Lite).
+    """
+
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def cache_dim(self) -> int:
+        """Values cached per position per layer: latent + rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+
+@dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN rotary scaling as DeepSeek-V2 defines it (``rope_scaling``)."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -93,6 +136,8 @@ class ModelConfig:
 
     # --- optional subsystems ---
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None  # latent attention in place of GQA
+    yarn: Optional[YaRNConfig] = None  # YaRN-scaled rotary frequencies
     ssm: Optional[SSMConfig] = None
     encoder: Optional[EncoderConfig] = None
 
@@ -148,16 +193,24 @@ class ModelConfig:
             n += v * d  # output head
         per_layer = 0
         # attention block
-        attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+        if self.mla is not None:
+            m, h = self.mla, self.num_heads
+            attn = (d * h * m.qk_head_dim + d * m.cache_dim + m.kv_lora_rank
+                    + m.kv_lora_rank * h * (m.qk_nope_head_dim + m.v_head_dim)
+                    + h * m.v_head_dim * d)
+        else:
+            attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
         if self.qkv_bias:
             attn += self.q_dim + 2 * self.kv_dim
         # mlp block
+        mlp_mats = 3 if self.mlp_act == "swiglu" else 2
+        first_dense = 0
         if self.moe is not None:
             e = self.moe
-            mlp_mats = 3 if self.mlp_act == "swiglu" else 2
             mlp = e.num_experts * (mlp_mats * d * e.d_ff_expert) + d * e.num_experts
+            mlp += e.num_shared_experts * mlp_mats * d * e.d_ff_expert
+            first_dense = e.first_k_dense
         else:
-            mlp_mats = 3 if self.mlp_act == "swiglu" else 2
             mlp = mlp_mats * d * self.d_ff
         if self.ssm is not None and self.ssm.kind == "mamba2":
             # Zamba2-style: mamba2 mixer per layer, NO per-layer MLP; the MLP
@@ -185,6 +238,7 @@ class ModelConfig:
         else:
             per_layer = attn + mlp
             n += self.num_layers * per_layer
+            n += first_dense * (mlp_mats * d * self.d_ff - mlp)
         if self.encoder is not None:
             enc_attn = 4 * d * d
             enc_mlp = mlp_mats * d * self.d_ff
@@ -201,7 +255,8 @@ class ModelConfig:
         mlp_mats = 3 if self.mlp_act == "swiglu" else 2
         full_experts = e.num_experts * (mlp_mats * self.d_model * e.d_ff_expert)
         active_experts = e.experts_per_token * (mlp_mats * self.d_model * e.d_ff_expert)
-        return self.param_count() - self.num_layers * (full_experts - active_experts)
+        moe_layers = self.num_layers - e.first_k_dense
+        return self.param_count() - moe_layers * (full_experts - active_experts)
 
     def to_json(self) -> str:
         return json.dumps(dataclasses.asdict(self), indent=2, default=str)
@@ -302,6 +357,11 @@ def reduce_for_smoke(cfg: ModelConfig) -> ModelConfig:
     if cfg.moe is not None:
         kw["moe"] = dataclasses.replace(
             cfg.moe, num_experts=4, experts_per_token=2, d_ff_expert=64
+        )
+    if cfg.mla is not None:
+        kw["mla"] = dataclasses.replace(
+            cfg.mla, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+            v_head_dim=16,
         )
     if cfg.ssm is not None:
         kw["ssm"] = dataclasses.replace(

@@ -29,6 +29,7 @@ from repro.configs import (
     granite_moe_1b_a400m,
     zamba2_2_7b,
     whisper_tiny,
+    deepseek_v2_lite,
 )
 
 _MODULES = {
@@ -42,6 +43,7 @@ _MODULES = {
     "granite-moe-1b-a400m": granite_moe_1b_a400m,
     "zamba2-2.7b": zamba2_2_7b,
     "whisper-tiny": whisper_tiny,
+    "deepseek-v2-lite": deepseek_v2_lite,
 }
 
 ARCH_NAMES: List[str] = list(_MODULES)

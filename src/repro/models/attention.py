@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.common import Params, dense_init, dtype_of, rms_norm, split_keys
-from repro.models.rope import apply_rope
+from repro.models.rope import apply_rope, apply_rope_pairs, yarn_softmax_scale
 
 NEG_INF = -1e30
 
@@ -85,30 +85,35 @@ def flash_attention_jnp(
     chunk: int = 2048,
     causal: bool = True,
     logit_softcap: float = 0.0,
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """Online-softmax attention over (chunk x chunk) blocks.
 
-    q: (B, S, Hq, hd); k, v: (B, S, Hkv, hd); Hq % Hkv == 0 (GQA).
-    Returns (B, S, Hq, hd). fp32 accumulators throughout.
+    q, k: (B, S, Hq|Hkv, hd); v: (B, S, Hkv, vd); Hq % Hkv == 0 (GQA).
+    Scores are scaled by ``scale`` (default hd**-0.5).
+    Returns (B, S, Hq, vd). fp32 accumulators throughout.
     """
     B, S, Hq, hd = q.shape
     Hkv = k.shape[2]
+    vd = v.shape[-1]
     G = Hq // Hkv
     C = min(chunk, S)
     pad = (-S) % C
     if pad:
         zq = jnp.zeros((B, pad, Hq, hd), q.dtype)
         zk = jnp.zeros((B, pad, Hkv, hd), k.dtype)
+        zv = jnp.zeros((B, pad, Hkv, vd), v.dtype)
         q = jnp.concatenate([q, zq], axis=1)
         k = jnp.concatenate([k, zk], axis=1)
-        v = jnp.concatenate([v, zk], axis=1)
+        v = jnp.concatenate([v, zv], axis=1)
     Sp = S + pad
     n = Sp // C
-    scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
 
     qc = q.reshape(B, n, C, Hkv, G, hd)
     kc = k.reshape(B, n, C, Hkv, hd)
-    vc = v.reshape(B, n, C, Hkv, hd)
+    vc = v.reshape(B, n, C, Hkv, vd)
 
     if causal:
         pairs = [(i, j) for i in range(n) for j in range(i + 1)]
@@ -124,7 +129,7 @@ def flash_attention_jnp(
 
     m0 = jnp.full((n, B, Hkv, G, C), NEG_INF, jnp.float32)
     l0 = jnp.zeros((n, B, Hkv, G, C), jnp.float32)
-    a0 = jnp.zeros((n, B, Hkv, G, C, hd), jnp.float32)
+    a0 = jnp.zeros((n, B, Hkv, G, C, vd), jnp.float32)
 
     def body(carry, idx):
         m, l, acc = carry
@@ -162,8 +167,8 @@ def flash_attention_jnp(
 
     (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0), (qi, kj))
     out = acc / jnp.maximum(l[..., None], 1e-30)
-    # (n, B, Hkv, G, C, hd) -> (B, S, Hq, hd)
-    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(B, Sp, Hq, hd)
+    # (n, B, Hkv, G, C, vd) -> (B, S, Hq, vd)
+    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(B, Sp, Hq, vd)
     return out[:, :S].astype(q.dtype)
 
 
@@ -175,15 +180,21 @@ def naive_attention(
     causal: bool = True,
     logit_softcap: float = 0.0,
     kv_mask: Optional[jnp.ndarray] = None,
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
-    """Reference softmax attention (materializes scores). Small shapes only."""
+    """Reference softmax attention (materializes scores). Small shapes only.
+    v may have its own head size; scores are scaled by ``scale`` (default
+    hd**-0.5)."""
     B, S, Hq, hd = q.shape
     Hkv = k.shape[2]
     M = k.shape[1]
     G = Hq // Hkv
     qg = q.reshape(B, S, Hkv, G, hd).astype(jnp.float32)
     s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k.astype(jnp.float32))
-    s = s / jnp.sqrt(hd)
+    if scale is None:
+        s = s / jnp.sqrt(hd)
+    else:
+        s = s * scale
     if logit_softcap > 0.0:
         s = logit_softcap * jnp.tanh(s / logit_softcap)
     if causal:
@@ -193,7 +204,7 @@ def naive_attention(
         s = s + jnp.where(kv_mask, 0.0, NEG_INF)[:, None, None, None, :]
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhgqk,bkhd->bqhgd", p, v.astype(jnp.float32))
-    return o.reshape(B, S, Hq, hd).astype(q.dtype)
+    return o.reshape(B, S, Hq, v.shape[-1]).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -317,6 +328,112 @@ def attention_extend(
     o = jnp.einsum("bhgqk,bkhd->bqhgd", pr, vals.astype(jnp.float32))
     out = o.reshape(B, S, cfg.q_dim).astype(q.dtype) @ p["wo"]
     return out, (k, v)
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2)
+# ---------------------------------------------------------------------------
+#
+# A position's cache row is [c_kv (kv_lora_rank) after its RMSNorm, k_pe
+# (qk_rope_head_dim) after RoPE]. Prefill expands per-head keys
+# [k_nope, k_pe] and values from the latent through ``wkv_b``; decode
+# attends in the latent space instead: the key half of ``wkv_b`` is folded
+# into the query and the value half applied after the weighted sum, so a
+# step reads the cache row and nothing per head.
+
+
+def mla_init(cfg, key) -> Params:
+    m, d, H = cfg.mla, cfg.d_model, cfg.num_heads
+    dt = dtype_of(cfg.param_dtype)
+    ks = split_keys(key, 4)
+    return {
+        "wq": dense_init(ks[0], (d, H * m.qk_head_dim), dt),
+        "wkv_a": dense_init(ks[1], (d, m.cache_dim), dt),
+        "kv_norm_scale": jnp.ones((m.kv_lora_rank,), jnp.float32),
+        "wkv_b": dense_init(ks[2], (m.kv_lora_rank, H * (m.qk_nope_head_dim + m.v_head_dim)), dt),
+        "wo": dense_init(ks[3], (H * m.v_head_dim, d), dt),
+    }
+
+
+def mla_cache_rows(cfg, p: Params, x: jnp.ndarray, cos, sin) -> jnp.ndarray:
+    """x (B, S, D) -> cache rows (B, S, kv_lora_rank + qk_rope_head_dim)."""
+    r = cfg.mla.kv_lora_rank
+    kv = x @ p["wkv_a"]
+    c_kv = rms_norm(kv[..., :r], p["kv_norm_scale"])
+    k_pe = apply_rope_pairs(kv[..., r:], cos, sin)
+    return jnp.concatenate([c_kv, k_pe], axis=-1)
+
+
+def _mla_query(cfg, p: Params, x: jnp.ndarray, cos, sin):
+    m = cfg.mla
+    B, S, _ = x.shape
+    q = (x @ p["wq"]).reshape(B, S, cfg.num_heads, m.qk_head_dim)
+    q_pe = apply_rope_pairs(q[..., m.qk_nope_head_dim:], cos[:, :, None], sin[:, :, None])
+    return q[..., : m.qk_nope_head_dim], q_pe
+
+
+def _wkv_b(cfg, p: Params):
+    """(kv_lora_rank, H, nope + v) view of the latent's up-projection."""
+    m = cfg.mla
+    return p["wkv_b"].reshape(m.kv_lora_rank, cfg.num_heads, m.qk_nope_head_dim + m.v_head_dim)
+
+
+def mla_attention_seq(cfg, p: Params, x: jnp.ndarray, cos, sin):
+    """Causal MLA over a whole sequence in the expanded form.
+    Returns (out (B, S, D), cache rows (B, S, C))."""
+    m = cfg.mla
+    B, S, _ = x.shape
+    with jax.named_scope("mla.attend"):
+        rows = mla_cache_rows(cfg, p, x, cos, sin)
+        q_nope, q_pe = _mla_query(cfg, p, x, cos, sin)
+        kv = jnp.einsum("bsr,rhd->bshd", rows[..., : m.kv_lora_rank], _wkv_b(cfg, p))
+        k_pe = jnp.broadcast_to(rows[:, :, None, m.kv_lora_rank:],
+                                (B, S, cfg.num_heads, m.qk_rope_head_dim))
+        q = jnp.concatenate([q_nope, q_pe], axis=-1)
+        k = jnp.concatenate([kv[..., : m.qk_nope_head_dim], k_pe], axis=-1)
+        v = kv[..., m.qk_nope_head_dim:]
+        scale = yarn_softmax_scale(m.qk_head_dim, cfg.yarn)
+        if S > 512:
+            o = flash_attention_jnp(q, k, v, causal=True, scale=scale,
+                                    chunk=getattr(cfg, "attn_chunk", 2048))
+        else:
+            o = naive_attention(q, k, v, causal=True, scale=scale)
+        out = o.reshape(B, S, cfg.num_heads * m.v_head_dim) @ p["wo"]
+    return out, rows
+
+
+def mla_attention_decode(cfg, p: Params, x: jnp.ndarray, cos, sin, cache: jnp.ndarray,
+                         layer, length: jnp.ndarray):
+    """One token in the absorbed form. x: (B, 1, D); cache: the whole
+    (L, B, M, C) latent cache, of which row ``layer`` is this layer's; the
+    token's row is written at ``length`` and attends over [0, length].
+    Returns (out (B, 1, D), cache)."""
+    m = cfg.mla
+    r = m.kv_lora_rank
+    B = x.shape[0]
+    M = cache.shape[2]
+    with jax.named_scope("mla.attend"):
+        row = mla_cache_rows(cfg, p, x, cos, sin)  # (B, 1, C)
+        pos = jnp.minimum(length, M - 1)
+        cache = jax.lax.dynamic_update_slice(
+            cache, row[None].astype(cache.dtype), (layer, 0, pos, 0))
+        lat = jax.lax.dynamic_index_in_dim(cache, layer, axis=0, keepdims=False)
+        lat = lat.astype(jnp.float32)  # (B, M, C)
+        q_nope, q_pe = _mla_query(cfg, p, x, cos, sin)
+        w = _wkv_b(cfg, p)
+        # keys k_nope = c_kv @ w_k, so q_nope . k_nope = (q_nope @ w_k^T) . c_kv
+        q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w[..., : m.qk_nope_head_dim],
+                           preferred_element_type=jnp.float32)
+        qc = jnp.concatenate([q_lat, q_pe.astype(jnp.float32)], axis=-1)
+        s = jnp.einsum("bshc,bmc->bhsm", qc, lat)
+        s = s * yarn_softmax_scale(m.qk_head_dim, cfg.yarn)
+        s = jnp.where((jnp.arange(M) <= pos)[None, None, None], s, NEG_INF)
+        pr = jax.nn.softmax(s, axis=-1)
+        o_lat = jnp.einsum("bhsm,bmr->bshr", pr, lat[..., :r])
+        # values v = c_kv @ w_v, applied after the weighted sum
+        o = jnp.einsum("bshr,rhd->bshd", o_lat, w[..., m.qk_nope_head_dim:].astype(jnp.float32))
+        out = o.reshape(B, 1, cfg.num_heads * m.v_head_dim).astype(x.dtype) @ p["wo"]
+    return out, cache
 
 
 # ---------------------------------------------------------------------------

@@ -7,7 +7,13 @@ all-gathers live inside the loop body (roofline.py multiplies while-body
 costs by trip count, so accounting stays exact).
 
 Caches are explicit pytrees (see ``init_cache``), so serving code jits
-``decode_step`` with donated cache buffers.
+``decode_step`` with donated cache buffers. A latent-attention (MLA) model
+(DeepSeek-V2: MLA with DeepSeekMoE experts, the only MLA stack served)
+caches one ``(L, B, M, kv_lora_rank + qk_rope_head_dim)`` array
+(``kv_latent``) in place of ``kv_k``/``kv_v``, and the routed (token,
+expert) pairs per MoE layer and expert so far (``expert_tokens``,
+``(L_moe, E)`` int32), summed on the device by prefill and each decode
+step.
 """
 
 from __future__ import annotations
@@ -34,10 +40,13 @@ from repro.models.common import (
     norm_param_init,
     split_keys,
 )
-from repro.models.rope import positions_for_rope
+from repro.models.rope import positions_for_rope, yarn_angles
 
 Batch = Dict[str, jnp.ndarray]
 Cache = Dict[str, jnp.ndarray]
+
+# cache key of the routed (token, expert) pairs per MoE layer and expert
+EXPERT_TOKENS = "expert_tokens"
 
 
 @dataclass(frozen=True)
@@ -146,6 +155,31 @@ def _whisper_dec_layer_init(cfg, key) -> Params:
     return p
 
 
+def _mla_layer_init(cfg, key, dense: bool) -> Params:
+    ks = split_keys(key, 2)
+    p: Params = {}
+    p.update({f"ln1_{k}": v for k, v in norm_param_init(cfg, cfg.d_model).items()})
+    p.update({f"ln2_{k}": v for k, v in norm_param_init(cfg, cfg.d_model).items()})
+    p["attn"] = attn.mla_init(cfg, ks[0])
+    if dense:
+        p["mlp"] = mlp_init(cfg, ks[1], cfg.d_model, cfg.d_ff)
+    else:
+        p["moe"] = moe_mod.moe_init(cfg, ks[1])
+    return p
+
+
+def _mla_dense_layers(cfg) -> int:
+    """Leading layers of an MLA stack with a dense MLP; the rest are MoE.
+
+    The MLA branch serves DeepSeek-V2 stacks only: latent attention with
+    DeepSeekMoE experts on ``moe_forward_dropless`` (``cfg.moe.mode`` is
+    not read). Latent attention over a dense MLP, or latent-attention MoE
+    on another expert path, would need a branch of its own."""
+    if cfg.moe is None:
+        raise NotImplementedError("an MLA stack is DeepSeek-V2's: MLA with DeepSeekMoE experts")
+    return cfg.moe.first_k_dense
+
+
 def init_params(cfg, key) -> Params:
     """Random-init parameters; structure is family-dependent but stable."""
     dt = dtype_of(cfg.param_dtype)
@@ -154,6 +188,15 @@ def init_params(cfg, key) -> Params:
     p.update(_norm_params(cfg, "final"))
     if not cfg.tie_embeddings:
         p["head"] = dense_init(ks[1], (cfg.d_model, cfg.vocab_size), dt)
+
+    if cfg.mla is not None:  # leading dense layers, then MoE layers
+        k = _mla_dense_layers(cfg)
+        if k:
+            p["dense_blocks"] = _stack(cfg, lambda c, kk: _mla_layer_init(c, kk, True),
+                                       ks[3], k)
+        p["blocks"] = _stack(cfg, lambda c, kk: _mla_layer_init(c, kk, False),
+                             ks[2], cfg.num_layers - k)
+        return p
 
     if cfg.family == "audio":
         enc = cfg.encoder
@@ -210,6 +253,10 @@ def init_cache(cfg, batch: int, max_len: int, *, enc_len: int = 0) -> Cache:
     """Zeroed cache pytree for ``batch`` sequences of up to ``max_len``."""
     kv_dt = jnp.bfloat16 if cfg.dtype == "bfloat16" else dtype_of(cfg.dtype)
     c: Cache = {"length": jnp.zeros((), jnp.int32)}
+    if cfg.mla is not None:
+        c["kv_latent"] = jnp.zeros((cfg.num_layers, batch, max_len, cfg.mla.cache_dim), kv_dt)
+        c[EXPERT_TOKENS] = jnp.zeros(expert_tokens_shape(cfg), jnp.int32)
+        return c
     if cfg.family == "audio":
         L = cfg.num_layers
         c["kv_k"] = jnp.zeros((L, batch, max_len, cfg.num_kv_heads, cfg.head_dim), kv_dt)
@@ -244,6 +291,15 @@ def init_cache(cfg, batch: int, max_len: int, *, enc_len: int = 0) -> Cache:
     c["kv_k"] = jnp.zeros((L, batch, max_len, cfg.num_kv_heads, cfg.head_dim), kv_dt)
     c["kv_v"] = jnp.zeros_like(c["kv_k"])
     return c
+
+
+def expert_tokens_shape(cfg) -> Optional[Tuple[int, int]]:
+    """(MoE layers, experts) of the cache's ``EXPERT_TOKENS``, or None for
+    a model whose cache carries none. Only MLA (DeepSeek-V2) stacks count
+    their routed pairs: the other MoE paths (``moe_forward``) return none."""
+    if cfg.mla is None:
+        return None
+    return cfg.num_layers - _mla_dense_layers(cfg), cfg.moe.num_experts
 
 
 def abstract_cache(cfg, batch: int, max_len: int, **kw) -> Cache:
@@ -309,6 +365,8 @@ def forward(
     B, S, _ = x.shape
     bspec = None if ctx is None else P(ctx.batch_spec, ctx.seq_spec, None)
     x = _constrain(x, ctx, bspec)
+    if cfg.mla is not None:
+        return _mla_forward(cfg, params, x, pos, ctx, want_cache, cache_len)
     cos, sin = positions_for_rope(cfg, pos, cfg.head_dim)
 
     if cfg.family == "ssm":
@@ -375,6 +433,64 @@ def forward(
             "kv_k": k_all.astype(kv_dt),
             "kv_v": v_all.astype(kv_dt),
         }
+    return logits, aux, cache
+
+
+def _mla_angles(cfg, pos):
+    return yarn_angles(pos, cfg.mla.qk_rope_head_dim, cfg.rope_theta, cfg.yarn)
+
+
+def _split_experts(blocks):
+    """The MoE stack without its routed experts' weights (scanned per
+    layer), and those weights whole: a decode step slices its routed
+    experts straight out of the stack, where a per-layer slice of the
+    scan would have XLA copy all of the layer's experts first."""
+    experts = {n: blocks["moe"][n] for n in moe_mod.EXPERT_WEIGHTS if n in blocks["moe"]}
+    rest = {k: v for k, v in blocks["moe"].items() if k not in experts}
+    return {**blocks, "moe": rest}, experts
+
+
+def _mla_forward(cfg, params, x, pos, ctx, want_cache, cache_len):
+    B, S, _ = x.shape
+    bspec = None if ctx is None else P(ctx.batch_spec, ctx.seq_spec, None)
+    cos, sin = _mla_angles(cfg, pos)
+
+    def attend(p, xc):
+        h, rows = attn.mla_attention_seq(cfg, p["attn"], apply_norm(cfg, p, "ln1", xc), cos, sin)
+        return xc + h, rows
+
+    def dense_body(xc, p):
+        xc, rows = attend(p, xc)
+        xc = xc + mlp_forward(cfg, p["mlp"], apply_norm(cfg, p, "ln2", xc))
+        return _constrain(xc, ctx, bspec), rows
+
+    blocks, experts = _split_experts(params["blocks"])
+
+    def moe_body(carry, inp):
+        (xc, aux), (p, i) = carry, inp
+        xc, rows = attend(p, xc)
+        m, a, counts = moe_mod.moe_forward_dropless(
+            cfg, {**p["moe"], **experts}, apply_norm(cfg, p, "ln2", xc), layer=i)
+        return (_constrain(xc + m, ctx, bspec), aux + a), (rows, counts)
+
+    rows = []
+    if "dense_blocks" in params:
+        x, r0 = jax.lax.scan(_maybe_remat(dense_body, ctx), x, params["dense_blocks"])
+        rows.append(r0)
+    (x, aux), (r1, counts) = jax.lax.scan(
+        _maybe_remat(moe_body, ctx), (x, jnp.zeros((), jnp.float32)),
+        (blocks, jnp.arange(cfg.num_layers - _mla_dense_layers(cfg))))
+    rows.append(r1)
+    logits = _logits(cfg, params, x)
+    cache = None
+    if want_cache:
+        kv_dt = jnp.bfloat16 if cfg.dtype == "bfloat16" else x.dtype
+        lat = jnp.concatenate(rows, axis=0).astype(kv_dt)  # (L, B, S, C)
+        M = cache_len or S
+        if M > S:
+            lat = jnp.pad(lat, ((0, 0), (0, 0), (0, M - S), (0, 0)))
+        cache = {"length": jnp.asarray(S, jnp.int32), "kv_latent": lat,
+                 EXPERT_TOKENS: counts}
     return logits, aux, cache
 
 
@@ -529,6 +645,11 @@ def prefill_extend(
             f"prefix extension requires a pure-KV cache; family "
             f"{cfg.family!r} carries recurrent state"
         )
+    if cfg.mla is not None:
+        raise NotImplementedError(
+            f"prefix extension requires per-head K/V; {cfg.name!r} caches a "
+            f"latent per position"
+        )
     tokens = batch["tokens"]
     B, S = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0).astype(dtype_of(cfg.dtype))
@@ -602,6 +723,8 @@ def decode_step(
     B = tokens.shape[0]
     x = jnp.take(params["embed"], tokens, axis=0).astype(dtype_of(cfg.dtype))
     pos = jnp.broadcast_to(length[None, None], (B, 1)).astype(jnp.int32)
+    if cfg.mla is not None:
+        return _mla_decode(cfg, params, cache, x, pos)
     cos, sin = positions_for_rope(cfg, pos, cfg.head_dim)
 
     if cfg.family == "ssm":
@@ -653,6 +776,43 @@ def decode_step(
     x, (ck, cv) = jax.lax.scan(body, x, (params["blocks"], cache["kv_k"], cache["kv_v"]))
     logits = _logits(cfg, params, x)
     return logits, {"length": length + 1, "kv_k": ck, "kv_v": cv}
+
+
+def _mla_decode(cfg, params, cache, x, pos):
+    """The whole latent cache rides the layer loops' carry: each layer
+    writes its new row in place and reads its own rows."""
+    length = cache["length"]
+    cos, sin = _mla_angles(cfg, pos)
+    k = _mla_dense_layers(cfg)
+
+    def attend(p, xc, lat, layer):
+        h, lat = attn.mla_attention_decode(cfg, p["attn"], apply_norm(cfg, p, "ln1", xc),
+                                           cos, sin, lat, layer, length)
+        return xc + h, lat
+
+    def dense_body(carry, inp):
+        (xc, lat), (p, layer) = carry, inp
+        xc, lat = attend(p, xc, lat, layer)
+        return (xc + mlp_forward(cfg, p["mlp"], apply_norm(cfg, p, "ln2", xc)), lat), None
+
+    blocks, experts = _split_experts(params["blocks"])
+
+    def moe_body(carry, inp):
+        (xc, lat), (p, layer) = carry, inp
+        xc, lat = attend(p, xc, lat, layer)
+        m, _, counts = moe_mod.moe_forward_dropless(
+            cfg, {**p["moe"], **experts}, apply_norm(cfg, p, "ln2", xc), layer=layer - k)
+        return (xc + m, lat), counts
+
+    carry = (x, cache["kv_latent"])
+    if k:
+        carry, _ = jax.lax.scan(dense_body, carry,
+                                (params["dense_blocks"], jnp.arange(k)))
+    (x, lat), counts = jax.lax.scan(moe_body, carry,
+                                    (blocks, jnp.arange(k, cfg.num_layers)))
+    logits = _logits(cfg, params, x)
+    return logits, {"length": length + 1, "kv_latent": lat,
+                    EXPERT_TOKENS: cache[EXPERT_TOKENS] + counts}
 
 
 def _hybrid_decode(cfg, params, cache, x, cos, sin, ctx):

@@ -1,6 +1,13 @@
 """Mixture-of-Experts layers.
 
-Three execution paths, one parameterization:
+Four execution paths, one parameterization:
+
+  * ``moe_forward_dropless`` — exact top-k MoE for serving (DeepSeekMoE
+    too: shared experts, unrenormalised weights). The path follows the
+    static token count: with no more routed (token, expert) pairs than
+    experts (a decode step), only the routed experts' weights are gathered
+    and computed; otherwise tokens are sorted by expert into grouped
+    matmuls. No token is dropped on either path.
 
   * ``moe_forward_grouped`` — exact dropless top-k MoE: sort tokens by expert
     and run grouped matmuls via ``jax.lax.ragged_dot``. Single-device
@@ -28,7 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.models.common import Params, dense_init, dtype_of, split_keys
+from repro.models.common import Params, dense_init, dtype_of, mlp_forward, mlp_init, split_keys
 
 
 # ---------------------------------------------------------------------------
@@ -48,6 +55,9 @@ def moe_init(cfg, key) -> Params:
     }
     if cfg.mlp_act == "swiglu":
         p["w_gate"] = dense_init(ks[1], (e.num_experts, d, e.d_ff_expert), dt)
+    if e.num_shared_experts:
+        p["shared"] = mlp_init(cfg, jax.random.fold_in(key, 5), d,
+                               e.num_shared_experts * e.d_ff_expert)
     return p
 
 
@@ -57,7 +67,8 @@ def _routing(cfg, p: Params, x2d: jnp.ndarray):
     logits = x2d.astype(jnp.float32) @ p["router"]  # (T, E)
     probs = jax.nn.softmax(logits, axis=-1)
     topk_w, topk_idx = jax.lax.top_k(probs, e.experts_per_token)
-    topk_w = topk_w / jnp.maximum(jnp.sum(topk_w, axis=-1, keepdims=True), 1e-9)
+    if e.norm_topk_prob:
+        topk_w = topk_w / jnp.maximum(jnp.sum(topk_w, axis=-1, keepdims=True), 1e-9)
     # load-balancing aux loss
     T = x2d.shape[0]
     onehot = jax.nn.one_hot(topk_idx, e.num_experts, dtype=jnp.float32)  # (T,K,E)
@@ -86,24 +97,91 @@ def _expert_ffn(cfg, p: Params, h: jnp.ndarray, group_sizes: jnp.ndarray):
 
 def moe_forward_grouped(cfg, p: Params, x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Dropless: every routed (token, expert) pair is computed."""
+    B, S, D = x.shape
+    x2d = x.reshape(B * S, D)
+    _, topk_w, topk_idx, aux = _routing(cfg, p, x2d)
+    y2d = _routed_grouped(cfg, p, x2d, topk_w, topk_idx, None)
+    return y2d.reshape(B, S, D).astype(x.dtype), aux
+
+
+# ---------------------------------------------------------------------------
+# Dropless serving path (routed gather at decode, grouped otherwise)
+# ---------------------------------------------------------------------------
+
+
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
+def _routed_gather(cfg, p: Params, x2d, topk_w, topk_idx, layer):
+    """Only the routed experts' weights are read: a loop over the routed
+    (token, expert) pairs slices each pair's expert out of the weights in
+    place (a ``gather`` would have XLA copy the whole stack first)."""
+    T, K = topk_idx.shape
+
+    def expert(name, e):
+        w = p[name]
+        if layer is None:
+            return jax.lax.dynamic_index_in_dim(w, e, keepdims=False)
+        start = (layer, e) + (0,) * (w.ndim - 2)
+        return jax.lax.dynamic_slice(w, start, (1, 1) + w.shape[2:]).reshape(w.shape[2:])
+
+    def pair(y, tew):
+        t, e, g = tew
+        xt = jax.lax.dynamic_index_in_dim(x2d, t)  # (1, D)
+        if cfg.mlp_act == "swiglu":
+            a = jax.nn.silu(xt @ expert("w_gate", e)) * (xt @ expert("w_up", e))
+        else:
+            u = xt @ expert("w_up", e)
+            a = jax.nn.relu(u) ** 2 if cfg.mlp_act == "squared_relu" else jax.nn.gelu(u)
+        o = jnp.dot(a, expert("w_down", e), preferred_element_type=jnp.float32)
+        yt = jax.lax.dynamic_index_in_dim(y, t) + g * o
+        return jax.lax.dynamic_update_index_in_dim(y, yt, t, 0), None
+
+    pairs = (jnp.repeat(jnp.arange(T), K), topk_idx.reshape(-1), topk_w.reshape(-1))
+    y, _ = jax.lax.scan(pair, jnp.zeros((T, x2d.shape[1]), jnp.float32), pairs)
+    return y
+
+
+def _routed_grouped(cfg, p: Params, x2d, topk_w, topk_idx, layer):
+    """Every routed pair, sorted by expert into grouped matmuls."""
+    e = cfg.moe
+    if layer is not None:
+        p = {**p, **{n: jax.lax.dynamic_index_in_dim(p[n], layer, keepdims=False)
+                     for n in EXPERT_WEIGHTS if n in p}}
+    T, K = topk_idx.shape
+    flat_expert = topk_idx.reshape(-1)
+    order = jnp.argsort(flat_expert)  # stable
+    sorted_tokens = jnp.repeat(jnp.arange(T), K)[order]
+    group_sizes = jnp.bincount(flat_expert, length=e.num_experts).astype(jnp.int32)
+    out = _expert_ffn(cfg, p, x2d[sorted_tokens], group_sizes)
+    contrib = out.astype(jnp.float32) * topk_w.reshape(-1)[order][:, None]
+    return jnp.zeros((T, x2d.shape[1]), jnp.float32).at[sorted_tokens].add(contrib)
+
+
+def moe_forward_dropless(cfg, p: Params, x: jnp.ndarray, *, layer=None):
+    """Exact top-k MoE plus shared experts. Returns (y, aux, counts): counts
+    (E,) int32 is the routed (token, expert) pairs per expert.
+
+    With ``layer``, the expert weights in ``p`` are a whole stack
+    (L_moe, E, ...) and this is its MoE layer ``layer``: a layer loop then
+    passes the stack whole, and the routed gather reads the routed experts
+    straight from it instead of a copy of the layer's 64."""
     e = cfg.moe
     B, S, D = x.shape
     T = B * S
-    K = e.experts_per_token
     x2d = x.reshape(T, D)
-    _, topk_w, topk_idx, aux = _routing(cfg, p, x2d)
-
-    flat_expert = topk_idx.reshape(-1)  # (T*K,)
-    flat_token = jnp.repeat(jnp.arange(T), K)
-    order = jnp.argsort(flat_expert)  # stable
-    sorted_tokens = flat_token[order]
-    h = x2d[sorted_tokens]  # (T*K, D)
-    group_sizes = jnp.bincount(flat_expert, length=e.num_experts).astype(jnp.int32)
-    out_sorted = _expert_ffn(cfg, p, h, group_sizes)  # (T*K, D)
-    w_sorted = topk_w.reshape(-1)[order]
-    contrib = out_sorted * w_sorted[:, None].astype(out_sorted.dtype)
-    y2d = jnp.zeros((T, D), contrib.dtype).at[sorted_tokens].add(contrib)
-    return y2d.reshape(B, S, D).astype(x.dtype), aux
+    with jax.named_scope("moe.route"):
+        _, topk_w, topk_idx, aux = _routing(cfg, p, x2d)
+        counts = jnp.bincount(topk_idx.reshape(-1), length=e.num_experts).astype(jnp.int32)
+    with jax.named_scope("moe.experts"):
+        if T * e.experts_per_token <= e.num_experts:
+            y2d = _routed_gather(cfg, p, x2d, topk_w, topk_idx, layer)
+        else:
+            y2d = _routed_grouped(cfg, p, x2d, topk_w, topk_idx, layer)
+    if e.num_shared_experts:
+        with jax.named_scope("moe.shared"):
+            y2d = y2d + mlp_forward(cfg, p["shared"], x2d)
+    return y2d.reshape(B, S, D).astype(x.dtype), aux, counts
 
 
 # ---------------------------------------------------------------------------

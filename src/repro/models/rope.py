@@ -1,4 +1,5 @@
-"""Rotary position embeddings: standard RoPE and Qwen2-VL M-RoPE.
+"""Rotary position embeddings: standard RoPE, Qwen2-VL M-RoPE, and
+DeepSeek-V2's YaRN over interleaved pairs.
 
 Positions are explicit everywhere (no hidden state), so prefill, decode and
 chunked execution all share the same code path.
@@ -6,7 +7,10 @@ chunked execution all share the same code path.
 
 from __future__ import annotations
 
+import math
 from typing import Tuple
+
+import numpy as np
 
 import jax.numpy as jnp
 
@@ -79,3 +83,62 @@ def positions_for_rope(cfg, positions: jnp.ndarray, head_dim: int):
             positions = jnp.broadcast_to(positions[None], (3,) + positions.shape)
         return mrope_angles(positions, head_dim, cfg.rope_theta)
     return rope_angles(positions, head_dim, cfg.rope_theta)
+
+
+# ---------------------------------------------------------------------------
+# YaRN (DeepSeek-V2's ``rope_scaling`` of type "yarn")
+# ---------------------------------------------------------------------------
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _correction_dim(rotations: float, dim: int, theta: float, max_pos: int) -> float:
+    """The pair index whose wavelength fits ``rotations`` turns in ``max_pos``."""
+    return dim * math.log(max_pos / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+
+def yarn_inv_freq(dim: int, theta: float, yarn) -> np.ndarray:
+    """Per pair frequency: pairs below the fast correction index keep the
+    base frequency, pairs above the slow one are divided by ``factor``, and
+    a linear ramp blends the two in between."""
+    half = dim // 2
+    base = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    low = max(math.floor(_correction_dim(yarn.beta_fast, dim, theta,
+                                         yarn.original_max_position_embeddings)), 0)
+    high = min(math.ceil(_correction_dim(yarn.beta_slow, dim, theta,
+                                         yarn.original_max_position_embeddings)), dim - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    keep = 1.0 - ramp  # share of the unscaled frequency
+    return (base / yarn.factor * (1.0 - keep) + base * keep).astype(np.float32)
+
+
+def yarn_angles(positions: jnp.ndarray, dim: int, theta: float, yarn) -> Tuple:
+    """positions (...,) -> cos/sin (..., dim/2), scaled by YaRN's mscale
+    ratio (1 where ``mscale`` equals ``mscale_all_dim``)."""
+    ang = positions.astype(jnp.float32)[..., None] * jnp.asarray(
+        yarn_inv_freq(dim, theta, yarn))
+    m = yarn_mscale(yarn.factor, yarn.mscale) / yarn_mscale(yarn.factor, yarn.mscale_all_dim)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if m != 1.0:
+        cos, sin = cos * m, sin * m
+    return cos, sin
+
+
+def yarn_softmax_scale(head_dim: int, yarn) -> float:
+    """DeepSeek-V2's attention scale: head_dim**-0.5 times mscale_all_dim's
+    YaRN factor squared."""
+    scale = head_dim ** -0.5
+    if yarn is not None and yarn.mscale_all_dim:
+        scale *= yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2
+    return scale
+
+
+def apply_rope_pairs(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
+    """Rotation of interleaved pairs (x[2i], x[2i+1]) by angle i.
+    cos/sin broadcast against x's leading dims with a last axis of dim/2."""
+    pairs = x.reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
+    a, b = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)

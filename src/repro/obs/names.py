@@ -72,6 +72,7 @@ JIT_COMPILES = "jit.compiles"
 JIT_COMPILE_S = "jit.compile_s"
 
 ENGINE_HOST_SYNCS = "engine.host_syncs"
+MOE_EXPERT_TOKENS = "moe.expert_tokens"
 
 METRIC_NAMES = (
     "router.requests",
@@ -121,6 +122,7 @@ METRIC_NAMES = (
     "jit.compiles",
     "jit.compile_s",
     "engine.host_syncs",
+    "moe.expert_tokens",
 )
 
 # -- span kinds ------------------------------------------------------------

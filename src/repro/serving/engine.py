@@ -21,6 +21,13 @@ token as it is drawn, to pad finished rows and count the rows still
 active. The counter ``engine.host_syncs`` (in ``Engine.obs``) counts the
 engine's blocking reads.
 
+A latent-attention MoE model keeps, in its cache, the routed
+(token, expert) pairs per MoE layer and expert, summed on the device by
+prefill and every decode step; ``generate`` reads them with the tokens in
+its final fetch (a separate read only with ``eos_id``) into
+``EngineStats.expert_tokens``, the counter ``moe.expert_tokens`` and the
+``expert_tokens`` attribute of ``engine.generate``.
+
 ``EngineStats`` sums the host-clock seconds of prefill (up to its logits
 being ready on the device) and of each call's decode phase (from the first
 step's dispatch to the tokens being on the host), and the tokens they
@@ -59,6 +66,7 @@ from repro.models import lm
 from repro.obs import MetricsRegistry, trace_span
 from repro.obs.names import (
     ENGINE_HOST_SYNCS,
+    MOE_EXPERT_TOKENS,
     SPAN_ENGINE_DECODE,
     SPAN_ENGINE_GENERATE,
     SPAN_ENGINE_PREFILL,
@@ -79,6 +87,8 @@ class EngineStats:
     prefix_tokens_reused: int = 0
     prefill_s: float = 0.0
     decode_s: float = 0.0
+    # routed (token, expert) pairs, (MoE layers, experts); None without them
+    expert_tokens: Optional[np.ndarray] = None
 
 
 class Engine:
@@ -102,6 +112,11 @@ class Engine:
         self.stats = EngineStats()
         self.obs = obs if obs is not None else MetricsRegistry()
         self._host_syncs = self.obs.counter(ENGINE_HOST_SYNCS, **(obs_labels or {}))
+        routed = lm.expert_tokens_shape(cfg)
+        self._expert_counters = None if routed is None else [
+            [self.obs.counter(MOE_EXPERT_TOKENS, **(obs_labels or {}),
+                              layer=str(l), expert=str(e)) for e in range(routed[1])]
+            for l in range(routed[0])]
         self.kv_prefix = kv_prefix if cfg.family in _PREFIX_FAMILIES else None
         ctx = None
         if mesh is not None:
@@ -192,6 +207,7 @@ class Engine:
         pool under ``template_id`` (batch row 0 — the template prefix is
         identical across rows by construction). Call right after the full
         prefill that built ``cache``, before decode donates its buffers."""
+        self._refuse_latent_prefix()
         if self.kv_prefix is None or "kv_k" not in cache:
             return False
         k = cache["kv_k"][:, 0]  # (L, M, Hkv, hd)
@@ -220,9 +236,18 @@ class Engine:
         from it — so a mismatched entry (stale registration, re-tokenized
         template) is treated as a miss, never served.
         """
+        self._refuse_latent_prefix()
         res = self._prefill_with_prefix_on_device(
             template_id, suffix_tokens, n_valid, expected_len)
         return None if res is None else (np.asarray(res[0]), res[1])
+
+    def _refuse_latent_prefix(self) -> None:
+        """The page pool holds per-head K/V; a latent cache has no place in
+        it yet, so prefix reuse is refused rather than skipped."""
+        if self.cfg.mla is not None:
+            raise NotImplementedError(
+                f"prefix reuse of a latent (MLA) cache is not implemented: "
+                f"{self.cfg.name} caches kv_latent, the page pool holds kv_k/kv_v")
 
     def _prefill_with_prefix_on_device(
         self, template_id: str, suffix_tokens: np.ndarray,
@@ -287,6 +312,8 @@ class Engine:
                 f"KV capacity (max_len={self.max_len}); decode would write "
                 f"past the cache"
             )
+        if cache_point is not None:
+            self._refuse_latent_prefix()
         n_valid = None if prompt_lengths is None else int(np.sum(prompt_lengths))
         with trace_span(SPAN_ENGINE_GENERATE, arch=self.cfg.name, batch=B,
                         prompt_len=S, max_new=max_new) as sp:
@@ -341,9 +368,27 @@ class Engine:
                         # first step's completion (see the module docstring)
                         self._wait(logits)
                 self.stats.decode_tokens += int(B - done.sum())
-            if eos_id is None:
+            routed = cache.get(lm.EXPERT_TOKENS)
+            if routed is not None:
+                # the routed pairs ride the final fetch of the tokens
+                got, routed = self._read((out if eos_id is None else [], routed))
+                if eos_id is None:
+                    out = got
+                self._count_experts(routed, sp)
+            elif eos_id is None:
                 out = self._read(out)
             if t0 is not None:
                 self.stats.decode_s += time.perf_counter() - t0
             sp.set(new_tokens=len(out))
             return np.concatenate(out, axis=1)
+
+    def _count_experts(self, routed: np.ndarray, span) -> None:
+        """Adds one call's routed pairs (MoE layers, experts) to the stats,
+        the counters and the call's span."""
+        routed = np.asarray(routed, np.int64)
+        st = self.stats
+        st.expert_tokens = routed if st.expert_tokens is None else st.expert_tokens + routed
+        for row, counters in zip(routed.tolist(), self._expert_counters):
+            for n, c in zip(row, counters):
+                c.inc(n)
+        span.set(expert_tokens=routed.tolist())

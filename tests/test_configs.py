@@ -7,7 +7,7 @@ from repro.configs.base import SHAPES, supports_shape
 
 
 def test_all_archs_registered():
-    assert len(registry.ARCH_NAMES) == 10
+    assert len(registry.ARCH_NAMES) == 11
 
 
 @pytest.mark.parametrize("arch", registry.ARCH_NAMES)
@@ -24,7 +24,7 @@ def test_param_counts_match_public_figures():
         "qwen3-4b": 4.4e9, "olmo-1b": 1.2e9, "nemotron-4-15b": 15.6e9,
         "qwen2.5-3b": 3.4e9, "rwkv6-3b": 3.1e9, "qwen2-vl-7b": 7.6e9,
         "kimi-k2-1t-a32b": 1.04e12, "granite-moe-1b-a400m": 1.3e9,
-        "zamba2-2.7b": 2.4e9, "whisper-tiny": 6e7,
+        "zamba2-2.7b": 2.4e9, "whisper-tiny": 6e7, "deepseek-v2-lite": 15.7e9,
     }
     for arch, n in expect.items():
         got = registry.get(arch).param_count()
@@ -45,8 +45,8 @@ def test_long_500k_rules():
 
 
 def test_cell_count():
-    # 10 archs x 4 shapes - 8 skipped long_500k = 32
-    assert len(registry.all_cells()) == 32
+    # 11 archs x 4 shapes - 9 skipped long_500k = 35
+    assert len(registry.all_cells()) == 35
 
 
 @pytest.mark.parametrize("arch", registry.ARCH_NAMES)

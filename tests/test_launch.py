@@ -19,7 +19,11 @@ def test_input_specs_all_cells(arch, shape_name):
     if shape.kind == "decode":
         assert batch["tokens"].shape == (shape.global_batch, 1)
         assert cache is not None and "length" in cache
-        if cfg.family in ("dense", "moe", "vlm"):
+        if cfg.mla is not None:  # one latent row per position and layer
+            L, B, M, C = cache["kv_latent"].shape
+            assert (L, B, C) == (cfg.num_layers, shape.global_batch, cfg.mla.cache_dim)
+            assert M >= shape.seq_len
+        elif cfg.family in ("dense", "moe", "vlm"):
             L, B, M, H, hd = cache["kv_k"].shape
             assert (L, B, H, hd) == (
                 cfg.num_layers, shape.global_batch, cfg.num_kv_heads, cfg.head_dim
