@@ -11,9 +11,10 @@ Design notes (TPU adaptation):
   * The Pallas kernel (kernels/flash_attention.py) implements the same tiling
     for real TPUs; this jnp version is the XLA path used by the dry-run and
     as the numerical oracle.
-  * Decode is a plain einsum over the KV cache (memory-bound; no benefit from
-    chunking at batch sizes of interest) — kernels/decode_attention.py is the
-    TPU kernel analogue.
+  * Decode is a plain multiply-reduce over the KV cache (memory-bound; no
+    benefit from chunking at batch sizes of interest), which the layer loop
+    carries and writes one row of in place — kernels/decode_attention.py is
+    the TPU kernel analogue.
 """
 
 from __future__ import annotations
@@ -207,6 +208,26 @@ def naive_attention(
     return o.reshape(B, S, Hq, v.shape[-1]).astype(q.dtype)
 
 
+def _one_query_attention(q, k, v, kv_mask, logit_softcap: float) -> jnp.ndarray:
+    """``naive_attention`` of one query position (q: (B, 1, Hq, hd)) over
+    k, v: (B, M, Hkv, hd) under kv_mask: (B, M), written as multiply-reduces
+    instead of grouped dot products. XLA then reads K and V in their stored,
+    position-major layout; a grouped dot wants them heads-major, and for a
+    cache carried through a layer loop XLA lays out the whole cache so,
+    copying it on the way into and out of the loop."""
+    B, _, Hq, hd = q.shape
+    Hkv = k.shape[2]
+    qg = q.reshape(B, 1, Hkv, Hq // Hkv, hd).astype(jnp.float32)
+    s = jnp.sum(qg * k.astype(jnp.float32)[:, :, :, None, :], axis=-1)  # (B, M, Hkv, G)
+    s = s / jnp.sqrt(hd)
+    if logit_softcap > 0.0:
+        s = logit_softcap * jnp.tanh(s / logit_softcap)
+    s = s + jnp.where(kv_mask, 0.0, NEG_INF)[:, :, None, None]
+    p = jax.nn.softmax(s, axis=1)
+    o = jnp.sum(p[..., None] * v.astype(jnp.float32)[:, :, :, None, :], axis=1)  # (B, Hkv, G, hd)
+    return o.reshape(B, 1, Hq, hd).astype(q.dtype)
+
+
 # ---------------------------------------------------------------------------
 # Layer-level entry points
 # ---------------------------------------------------------------------------
@@ -251,34 +272,35 @@ def attention_decode(
     sin,
     cache_k: jnp.ndarray,
     cache_v: jnp.ndarray,
+    layer,
     length: jnp.ndarray,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Single-token decode. x: (B, 1, D); cache: (B, M, Hkv, hd); length: ()
-    number of valid cached positions. Writes the new token's K/V at ``length``
-    and attends over positions [0, length].
-    Returns (out (B,1,D), new_cache_k, new_cache_v).
+    """Single-token decode. x: (B, 1, D); cache_k/v: the whole stacked
+    (L, B, M, Hkv, hd) K/V cache, of which row ``layer`` is this layer's;
+    length: () number of valid cached positions. Writes the new token's K/V
+    at ``(layer, :, length)`` and attends over positions [0, length].
+    Returns (out (B,1,D), cache_k, cache_v). The caller carries the stacked
+    cache through its layer loop, so the row is written in place (as
+    ``mla_attention_decode`` does) and no buffer the size of the cache is
+    copied.
     """
     B = x.shape[0]
-    M = cache_k.shape[1]
+    M = cache_k.shape[2]
     q, k, v = _project_qkv(cfg, p, x)  # (B, 1, H*, hd)
     if cos is not None:
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
     pos = jnp.minimum(length, M - 1)
-    ck = jax.lax.dynamic_update_slice(cache_k, k.astype(cache_k.dtype), (0, pos, 0, 0))
-    cv = jax.lax.dynamic_update_slice(cache_v, v.astype(cache_v.dtype), (0, pos, 0, 0))
+    at = (layer, 0, pos, 0, 0)
+    cache_k = jax.lax.dynamic_update_slice(cache_k, k[None].astype(cache_k.dtype), at)
+    cache_v = jax.lax.dynamic_update_slice(cache_v, v[None].astype(cache_v.dtype), at)
+    ck = jax.lax.dynamic_index_in_dim(cache_k, layer, axis=0, keepdims=False)
+    cv = jax.lax.dynamic_index_in_dim(cache_v, layer, axis=0, keepdims=False)
     kv_mask = jnp.arange(M)[None, :] <= pos  # (1, M) -> broadcast over batch
     kv_mask = jnp.broadcast_to(kv_mask, (B, M))
-    o = naive_attention(
-        q,
-        ck.astype(q.dtype),
-        cv.astype(q.dtype),
-        causal=False,
-        logit_softcap=cfg.attn_logit_softcap,
-        kv_mask=kv_mask,
-    )
+    o = _one_query_attention(q, ck, cv, kv_mask, cfg.attn_logit_softcap)
     out = o.reshape(B, 1, cfg.q_dim) @ p["wo"]
-    return out, ck, cv
+    return out, cache_k, cache_v
 
 
 def attention_extend(

@@ -7,7 +7,10 @@ all-gathers live inside the loop body (roofline.py multiplies while-body
 costs by trip count, so accounting stays exact).
 
 Caches are explicit pytrees (see ``init_cache``), so serving code jits
-``decode_step`` with donated cache buffers. A latent-attention (MLA) model
+``decode_step`` with donated cache buffers. Decode carries each stacked
+cache through its layer scan and writes a layer's new row in place, so the
+donated buffer is the returned one and no step copies the cache. A
+latent-attention (MLA) model
 (DeepSeek-V2: MLA with DeepSeekMoE experts, the only MLA stack served)
 caches one ``(L, B, M, kv_lora_rank + qk_rope_head_dim)`` array
 (``kv_latent``) in place of ``kv_k``/``kv_v``, and the routed (token,
@@ -760,10 +763,10 @@ def decode_step(
         return _whisper_decode(cfg, params, cache, x, ctx)
 
     def body(carry, inp):
-        xc = carry
-        p, ck, cv = inp
+        xc, ck, cv = carry
+        p, layer = inp
         h, ck, cv = attn.attention_decode(
-            cfg, p["attn"], apply_norm(cfg, p, "ln1", xc), cos, sin, ck, cv, length
+            cfg, p["attn"], apply_norm(cfg, p, "ln1", xc), cos, sin, ck, cv, layer, length
         )
         xc = xc + h
         if cfg.moe is not None:
@@ -771,16 +774,19 @@ def decode_step(
         else:
             m = mlp_forward(cfg, p["mlp"], apply_norm(cfg, p, "ln2", xc))
         xc = xc + m
-        return xc, (ck, cv)
+        return (xc, ck, cv), None
 
-    x, (ck, cv) = jax.lax.scan(body, x, (params["blocks"], cache["kv_k"], cache["kv_v"]))
+    (x, ck, cv), _ = jax.lax.scan(
+        body, (x, cache["kv_k"], cache["kv_v"]),
+        (params["blocks"], jnp.arange(cfg.num_layers)),
+    )
     logits = _logits(cfg, params, x)
     return logits, {"length": length + 1, "kv_k": ck, "kv_v": cv}
 
 
 def _mla_decode(cfg, params, cache, x, pos):
-    """The whole latent cache rides the layer loops' carry: each layer
-    writes its new row in place and reads its own rows."""
+    """Decode through the dense then MoE layer scans; the latent cache
+    rides their carry as the dense K/V cache rides ``decode_step``'s."""
     length = cache["length"]
     cos, sin = _mla_angles(cfg, pos)
     k = _mla_dense_layers(cfg)
@@ -821,8 +827,8 @@ def _hybrid_decode(cfg, params, cache, x, cos, sin, ctx):
     x2 = x[:, 0]
 
     def body(carry, inp):
-        xc = carry
-        p_group, st_g, cv_g, ck, cvv = inp
+        xc, ck, cvv = carry
+        p_group, st_g, cv_g, group = inp
         sts = []
         cvs = []
         for i in range(cfg.attn_every):
@@ -835,16 +841,18 @@ def _hybrid_decode(cfg, params, cache, x, cos, sin, ctx):
         # shared attention block (on (B,1,D))
         x3 = xc[:, None]
         h, ck, cvv = attn.attention_decode(
-            cfg, shared["attn"], apply_norm(cfg, shared, "ln1", x3), cos, sin, ck, cvv, length
+            cfg, shared["attn"], apply_norm(cfg, shared, "ln1", x3), cos, sin, ck, cvv,
+            group, length,
         )
         x3 = x3 + h
         x3 = x3 + mlp_forward(cfg, shared["mlp"], apply_norm(cfg, shared, "ln2", x3))
-        return x3[:, 0], (jnp.stack(sts), jnp.stack(cvs), ck, cvv)
+        return (x3[:, 0], ck, cvv), (jnp.stack(sts), jnp.stack(cvs))
 
-    x2, (st, cv, ck, cvv) = jax.lax.scan(
+    (x2, ck, cvv), (st, cv) = jax.lax.scan(
         body,
-        x2,
-        (params["blocks"], cache["ssm_state"], cache["conv"], cache["kv_k"], cache["kv_v"]),
+        (x2, cache["kv_k"], cache["kv_v"]),
+        (params["blocks"], cache["ssm_state"], cache["conv"],
+         jnp.arange(cache["kv_k"].shape[0])),
     )
     logits = _logits(cfg, params, x2[:, None])
     return logits, {
@@ -862,27 +870,26 @@ def _whisper_decode(cfg, params, cache, x, ctx):
     x = x + pos_emb[None].astype(x.dtype)
 
     def body(carry, inp):
-        xc = carry
-        p, ck, cv, crk, crv = inp
+        xc, ck, cv = carry
+        p, crk, crv, layer = inp
         h, ck, cv = attn.attention_decode(
-            cfg, p["attn"], apply_norm(cfg, p, "ln1", xc), None, None, ck, cv, length
+            cfg, p["attn"], apply_norm(cfg, p, "ln1", xc), None, None, ck, cv, layer, length
         )
         xc = xc + h
         xc = xc + attn.cross_attention(
             cfg, p["cross"], apply_norm(cfg, p, "ln2", xc), crk, crv
         )
         xc = xc + mlp_forward(cfg, p["mlp"], apply_norm(cfg, p, "ln3", xc))
-        return xc, (ck, cv)
+        return (xc, ck, cv), None
 
-    x, (ck, cv) = jax.lax.scan(
+    (x, ck, cv), _ = jax.lax.scan(
         body,
-        x,
+        (x, cache["kv_k"], cache["kv_v"]),
         (
             params["dec_blocks"],
-            cache["kv_k"],
-            cache["kv_v"],
             cache["cross_k"],
             cache["cross_v"],
+            jnp.arange(cfg.num_layers),
         ),
     )
     logits = _logits(cfg, params, x)

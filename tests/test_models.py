@@ -65,7 +65,9 @@ def test_prefill_decode_equivalence(arch, rng_key):
     assert int(cache2["length"]) == S + 1
 
 
-@pytest.mark.parametrize("arch", ["qwen3-4b", "rwkv6-3b", "zamba2-2.7b", "whisper-tiny"])
+@pytest.mark.parametrize(
+    "arch", ["qwen3-4b", "olmo-1b", "qwen2.5-3b", "rwkv6-3b", "zamba2-2.7b", "whisper-tiny"]
+)
 def test_multi_step_decode_matches_forward(arch, rng_key):
     cfg = _fp32(registry.get_smoke(arch))
     p = lm.init_params(cfg, rng_key)
@@ -83,6 +85,26 @@ def test_multi_step_decode_matches_forward(arch, rng_key):
         b = np.asarray(ld[:, 0], np.float32)
         rel = np.abs(a - b).max() / (np.abs(a).max() + 1e-9)
         assert rel < 1e-3, (arch, i, rel)
+
+
+@pytest.mark.parametrize("arch", ["qwen2.5-3b", "olmo-1b"])
+def test_decode_writes_cache_in_place(arch):
+    """The decode program writes each layer's K/V row into the donated
+    stacked cache: it copies no buffer of the cache's shape, and its
+    temporaries stay under two K buffers (a scan-in/scan-out layer loop
+    copies the cache three times and holds 3.5 K buffers)."""
+    cfg = _fp32(registry.get_smoke(arch))
+    cache = lm.abstract_cache(cfg, 1, 512)
+    step = jax.jit(lambda p, c, t: lm.decode_step(cfg, p, c, t), donate_argnums=(1,))
+    compiled = step.lower(
+        lm.abstract_params(cfg), cache, jax.ShapeDtypeStruct((1, 1), jnp.int32)
+    ).compile()
+    kv = cache["kv_k"]
+    stacked = "= f32[%s]" % ",".join(map(str, kv.shape))
+    copies = [l for l in compiled.as_text().splitlines() if stacked in l and " copy(" in l]
+    assert not copies, copies
+    kv_bytes = kv.size * kv.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * kv_bytes
 
 
 def test_vlm_embeds_path(rng_key):

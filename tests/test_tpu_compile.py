@@ -4,14 +4,18 @@ Each test lowers one kernel at published widths and compiles it for a
 described ``v5e:2x2`` topology (one chip of it). Interpret mode, which
 every other kernel test uses, accepts block shapes and primitives that
 Mosaic refuses; this file is where such a kernel fails on a CPU-only
-machine. Nothing runs: a compile that passes is not a chip run.
+machine. The dense decode program is compiled here too, for the layout
+the TPU compiler gives its cache. Nothing runs: a compile that passes is
+not a chip run.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and under pytest-xdist
 every worker imports every test file.
 """
 
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +26,7 @@ from repro.configs import registry
 from repro.index import DIM
 from repro.kernels import decode_attention, flash_attention, paged_attention
 from repro.kernels import similarity
+from repro.models import lm
 
 # (Hq, Hkv, hd) of the two tier models the one-chip deployment serves
 WIDTHS = {
@@ -114,3 +119,27 @@ def test_topk_cosine_compiles_at_deployment_bank(one_chip):
     # little beyond its arguments
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < N * DIM * 4
+
+
+@pytest.mark.parametrize("model", sorted(WIDTHS))
+def test_decode_step_writes_cache_in_place(model, one_chip):
+    """The dense decode program at published widths (two layers, max_len
+    4096) copies no buffer of the stacked cache's shape. On the TPU a
+    grouped (GQA) score product makes XLA lay the loop-carried cache out
+    heads-major and copy it whole into and out of the layer loop, which a
+    CPU compile does not show."""
+    cfg = dataclasses.replace(registry.get(model), num_layers=2)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+
+    cache = on_chip(lm.abstract_cache(cfg, 1, 4096))
+    step = jax.jit(lambda p, c, t: lm.decode_step(cfg, p, c, t), donate_argnums=(1,))
+    compiled = step.lower(
+        on_chip(lm.abstract_params(cfg)), cache,
+        jax.ShapeDtypeStruct((1, 1), jnp.int32, sharding=one_chip),
+    ).compile()
+    stacked = re.compile(r"= bf16\[%s\]\S* copy\(" % ",".join(map(str, cache["kv_k"].shape)))
+    copies = [l for l in compiled.as_text().splitlines() if stacked.search(l)]
+    assert not copies, copies
