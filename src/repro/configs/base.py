@@ -123,8 +123,6 @@ class ModelConfig:
     qk_norm: bool = False
     qkv_bias: bool = False
     attn_chunk: int = 2048  # flash block size (VMEM/temp-memory knob)
-    use_pallas: bool = False  # route attention through the Pallas kernels
-    #   (TPU: compiled Mosaic; CPU: interpret mode — tests only)
     rope_theta: float = 10_000.0
     rope_type: str = "rope"  # rope | mrope | none
     attn_logit_softcap: float = 0.0

@@ -9,8 +9,8 @@ Design notes (TPU adaptation):
     the number of causal blocks (roofline.py multiplies body FLOPs by trip
     count, so causal FLOP accounting is exact: nq*(nq+1)/2 blocks).
   * The Pallas kernel (kernels/flash_attention.py) implements the same tiling
-    for real TPUs; this jnp version is the XLA path used by the dry-run and
-    as the numerical oracle.
+    for real TPUs, but no model path calls it yet; this jnp version is the
+    path prefill runs, the dry-run's, and the numerical oracle.
   * Decode is a plain multiply-reduce over the KV cache (memory-bound; no
     benefit from chunking at batch sizes of interest), which the layer loop
     carries and writes one row of in place — kernels/decode_attention.py is
@@ -241,19 +241,16 @@ def attention_seq(
     sin,
     *,
     causal: bool = True,
-    use_flash: bool = True,
 ) -> Tuple[jnp.ndarray, Tuple[jnp.ndarray, jnp.ndarray]]:
-    """Full-sequence attention (train / prefill). Returns (out, (k, v))."""
+    """Full-sequence attention (train / prefill). Returns (out, (k, v)).
+    Past 512 positions it runs in the chunked flash form, which never
+    holds the (S, S) scores; shorter sequences use the reference form."""
     B, S, _ = x.shape
     q, k, v = _project_qkv(cfg, p, x)
     if cos is not None:
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-    if getattr(cfg, "use_pallas", False) and S % 128 == 0 and causal:
-        from repro.kernels import ops as kops
-
-        o = kops.flash_attention_op(q, k, v, causal=True)
-    elif use_flash and S > 512:
+    if S > 512:
         o = flash_attention_jnp(
             q, k, v, causal=causal, logit_softcap=cfg.attn_logit_softcap,
             chunk=getattr(cfg, "attn_chunk", 2048),

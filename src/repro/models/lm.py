@@ -6,13 +6,16 @@ Layer stacks run under ``lax.scan`` with stacked per-layer parameters
 all-gathers live inside the loop body (roofline.py multiplies while-body
 costs by trip count, so accounting stays exact).
 
-Caches are explicit pytrees (see ``init_cache``), so serving code jits
-``decode_step`` with donated cache buffers. Decode carries each stacked
-cache through its layer scan and writes a layer's new row in place, so the
-donated buffer is the returned one and no step copies the cache. A
-latent-attention (MLA) model
-(DeepSeek-V2: MLA with DeepSeekMoE experts, the only MLA stack served)
-caches one ``(L, B, M, kv_lora_rank + qk_rope_head_dim)`` array
+Each family's parameters, cache, forward and decode step are functions of
+one ``_Family`` entry, and ``_family`` alone chooses the entry for a
+config. Caches are explicit pytrees whose arrays, shapes and dtypes
+``init_cache`` alone decides: prefill and prefix extension write their rows
+into that layout (``_filled_cache``), so serving code jits ``decode_step``
+with donated cache buffers. Decode carries each stacked cache through its
+layer scan and writes a layer's new row in place, so the donated buffer is
+the returned one and no step copies the cache. A latent-attention (MLA)
+model (DeepSeek-V2: MLA with DeepSeekMoE experts, the only MLA stack
+served) caches one ``(L, B, M, kv_lora_rank + qk_rope_head_dim)`` array
 (``kv_latent``) in place of ``kv_k``/``kv_v``, and the routed (token,
 expert) pairs per MoE layer and expert so far (``expert_tokens``,
 ``(L_moe, E)`` int32), summed on the device by prefill and each decode
@@ -21,9 +24,8 @@ step.
 
 from __future__ import annotations
 
-import functools
-from dataclasses import dataclass, field
-from typing import Any, Dict, Optional, Tuple
+from dataclasses import dataclass
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -82,6 +84,11 @@ def _constrain(x, ctx: Optional[ParallelCtx], spec):
     )
 
 
+def _bspec(ctx: Optional[ParallelCtx]):
+    """The (batch, sequence, model) spec of activations under ``ctx``."""
+    return None if ctx is None else P(ctx.batch_spec, ctx.seq_spec, None)
+
+
 def _maybe_remat(fn, ctx: Optional[ParallelCtx]):
     mode = ctx.remat if ctx is not None else "none"
     if mode == "none":
@@ -98,18 +105,12 @@ def _maybe_remat(fn, ctx: Optional[ParallelCtx]):
 
 
 def _norm_params(cfg, key_prefix: str) -> Params:
-    out = {}
-    base = norm_param_init(cfg, cfg.d_model)
-    for k, v in base.items():
-        out[f"{key_prefix}_{k}"] = v
-    return out
+    return {f"{key_prefix}_{k}": v for k, v in norm_param_init(cfg, cfg.d_model).items()}
 
 
 def _dense_layer_init(cfg, key) -> Params:
     ks = split_keys(key, 2)
-    p: Params = {}
-    p.update({f"ln1_{k}": v for k, v in norm_param_init(cfg, cfg.d_model).items()})
-    p.update({f"ln2_{k}": v for k, v in norm_param_init(cfg, cfg.d_model).items()})
+    p: Params = {**_norm_params(cfg, "ln1"), **_norm_params(cfg, "ln2")}
     p["attn"] = attn.attn_init(cfg, ks[0])
     if cfg.moe is not None:
         p["moe"] = moe_mod.moe_init(cfg, ks[1])
@@ -119,39 +120,19 @@ def _dense_layer_init(cfg, key) -> Params:
 
 
 def _rwkv_layer_init(cfg, key) -> Params:
-    p: Params = {}
-    p.update({f"ln1_{k}": v for k, v in norm_param_init(cfg, cfg.d_model).items()})
-    p.update({f"ln2_{k}": v for k, v in norm_param_init(cfg, cfg.d_model).items()})
+    p: Params = {**_norm_params(cfg, "ln1"), **_norm_params(cfg, "ln2")}
     p["rwkv"] = rwkv_mod.rwkv_init(cfg, key)
     return p
 
 
 def _mamba_layer_init(cfg, key) -> Params:
-    p: Params = {}
-    p.update({f"ln1_{k}": v for k, v in norm_param_init(cfg, cfg.d_model).items()})
-    p["mamba"] = mamba_init_wrap(cfg, key)
-    return p
-
-
-def mamba_init_wrap(cfg, key):
-    return mam.mamba_init(cfg, key)
-
-
-def _whisper_enc_layer_init(cfg, key) -> Params:
-    ks = split_keys(key, 2)
-    p: Params = {}
-    p.update({f"ln1_{k}": v for k, v in norm_param_init(cfg, cfg.d_model).items()})
-    p.update({f"ln2_{k}": v for k, v in norm_param_init(cfg, cfg.d_model).items()})
-    p["attn"] = attn.attn_init(cfg, ks[0])
-    p["mlp"] = mlp_init(cfg, ks[1], cfg.d_model, cfg.d_ff)
-    return p
+    return {**_norm_params(cfg, "ln1"), "mamba": mam.mamba_init(cfg, key)}
 
 
 def _whisper_dec_layer_init(cfg, key) -> Params:
     ks = split_keys(key, 3)
-    p: Params = {}
-    for nm in ("ln1", "ln2", "ln3"):
-        p.update({f"{nm}_{k}": v for k, v in norm_param_init(cfg, cfg.d_model).items()})
+    p: Params = {**_norm_params(cfg, "ln1"), **_norm_params(cfg, "ln2"),
+                 **_norm_params(cfg, "ln3")}
     p["attn"] = attn.attn_init(cfg, ks[0])
     p["cross"] = attn.cross_attn_init(cfg, ks[1])
     p["mlp"] = mlp_init(cfg, ks[2], cfg.d_model, cfg.d_ff)
@@ -160,9 +141,7 @@ def _whisper_dec_layer_init(cfg, key) -> Params:
 
 def _mla_layer_init(cfg, key, dense: bool) -> Params:
     ks = split_keys(key, 2)
-    p: Params = {}
-    p.update({f"ln1_{k}": v for k, v in norm_param_init(cfg, cfg.d_model).items()})
-    p.update({f"ln2_{k}": v for k, v in norm_param_init(cfg, cfg.d_model).items()})
+    p: Params = {**_norm_params(cfg, "ln1"), **_norm_params(cfg, "ln2")}
     p["attn"] = attn.mla_init(cfg, ks[0])
     if dense:
         p["mlp"] = mlp_init(cfg, ks[1], cfg.d_model, cfg.d_ff)
@@ -191,55 +170,49 @@ def init_params(cfg, key) -> Params:
     p.update(_norm_params(cfg, "final"))
     if not cfg.tie_embeddings:
         p["head"] = dense_init(ks[1], (cfg.d_model, cfg.vocab_size), dt)
-
-    if cfg.mla is not None:  # leading dense layers, then MoE layers
-        k = _mla_dense_layers(cfg)
-        if k:
-            p["dense_blocks"] = _stack(cfg, lambda c, kk: _mla_layer_init(c, kk, True),
-                                       ks[3], k)
-        p["blocks"] = _stack(cfg, lambda c, kk: _mla_layer_init(c, kk, False),
-                             ks[2], cfg.num_layers - k)
-        return p
-
-    if cfg.family == "audio":
-        enc = cfg.encoder
-        p["enc_blocks"] = _stack(cfg, _whisper_enc_layer_init, ks[2], enc.num_layers)
-        p["dec_blocks"] = _stack(cfg, _whisper_dec_layer_init, ks[3], cfg.num_layers)
-        p.update({f"enc_final_{k}": v for k, v in norm_param_init(cfg, cfg.d_model).items()})
-        p["dec_pos"] = dense_init(ks[4], (32_776, cfg.d_model), dt, scale=0.01)
-        return p
-
-    if cfg.family == "ssm":  # rwkv6
-        p["blocks"] = _stack(cfg, _rwkv_layer_init, ks[2], cfg.num_layers)
-        p.update({f"ln0_{k}": v for k, v in norm_param_init(cfg, cfg.d_model).items()})
-        return p
-
-    if cfg.family == "hybrid":  # zamba2
-        groups = cfg.num_layers // cfg.attn_every
-        per = cfg.attn_every
-
-        def group_init(k):
-            return _stack(cfg, _mamba_layer_init, k, per)
-
-        p["blocks"] = _stack(cfg, lambda c, k: group_init(k), ks[2], groups)
-        p["shared_attn"] = _dense_layer_init(cfg, ks[3])
-        return p
-
-    # dense / moe / vlm
-    p["blocks"] = _stack(cfg, _dense_layer_init, ks[2], cfg.num_layers)
+    p.update(_family(cfg).params(cfg, ks, dt))
     return p
 
 
 def _stack(cfg, layer_init, key, n: int) -> Params:
-    keys = jax.random.split(key, n)
+    return jax.vmap(lambda k: layer_init(cfg, k))(jax.random.split(key, n))
 
-    def one(k):
-        try:
-            return layer_init(cfg, k)
-        except TypeError:
-            return layer_init(k)
 
-    return jax.vmap(one)(keys)
+def _dense_params(cfg, ks, dt) -> Params:
+    return {"blocks": _stack(cfg, _dense_layer_init, ks[2], cfg.num_layers)}
+
+
+def _mla_params(cfg, ks, dt) -> Params:
+    """Leading dense layers, then MoE layers."""
+    k = _mla_dense_layers(cfg)
+    p = {"blocks": _stack(cfg, lambda c, kk: _mla_layer_init(c, kk, False),
+                          ks[2], cfg.num_layers - k)}
+    if k:
+        p["dense_blocks"] = _stack(cfg, lambda c, kk: _mla_layer_init(c, kk, True), ks[3], k)
+    return p
+
+
+def _rwkv_params(cfg, ks, dt) -> Params:
+    return {"blocks": _stack(cfg, _rwkv_layer_init, ks[2], cfg.num_layers),
+            **_norm_params(cfg, "ln0")}
+
+
+def _hybrid_params(cfg, ks, dt) -> Params:
+    """Groups of ``attn_every`` Mamba layers, then one shared attention block."""
+    def group_init(c, k):
+        return _stack(c, _mamba_layer_init, k, c.attn_every)
+
+    return {"blocks": _stack(cfg, group_init, ks[2], cfg.num_layers // cfg.attn_every),
+            "shared_attn": _dense_layer_init(cfg, ks[3])}
+
+
+def _whisper_params(cfg, ks, dt) -> Params:
+    return {
+        "enc_blocks": _stack(cfg, _dense_layer_init, ks[2], cfg.encoder.num_layers),
+        "dec_blocks": _stack(cfg, _whisper_dec_layer_init, ks[3], cfg.num_layers),
+        **_norm_params(cfg, "enc_final"),
+        "dec_pos": dense_init(ks[4], (32_776, cfg.d_model), dt, scale=0.01),
+    }
 
 
 def abstract_params(cfg) -> Params:
@@ -253,60 +226,95 @@ def abstract_params(cfg) -> Params:
 
 
 def init_cache(cfg, batch: int, max_len: int, *, enc_len: int = 0) -> Cache:
-    """Zeroed cache pytree for ``batch`` sequences of up to ``max_len``."""
-    kv_dt = jnp.bfloat16 if cfg.dtype == "bfloat16" else dtype_of(cfg.dtype)
+    """Zeroed cache pytree for ``batch`` sequences of up to ``max_len``.
+
+    The one definition of a cache's arrays, shapes and dtypes: K/V and
+    latent caches are kept in the activation dtype, recurrent states as
+    each family's own function gives them."""
     c: Cache = {"length": jnp.zeros((), jnp.int32)}
-    if cfg.mla is not None:
-        c["kv_latent"] = jnp.zeros((cfg.num_layers, batch, max_len, cfg.mla.cache_dim), kv_dt)
-        c[EXPERT_TOKENS] = jnp.zeros(expert_tokens_shape(cfg), jnp.int32)
-        return c
-    if cfg.family == "audio":
-        L = cfg.num_layers
-        c["kv_k"] = jnp.zeros((L, batch, max_len, cfg.num_kv_heads, cfg.head_dim), kv_dt)
-        c["kv_v"] = jnp.zeros_like(c["kv_k"])
-        T = enc_len or cfg.encoder.num_frames
-        c["cross_k"] = jnp.zeros((L, batch, T, cfg.num_kv_heads, cfg.head_dim), kv_dt)
-        c["cross_v"] = jnp.zeros_like(c["cross_k"])
-        return c
-    if cfg.family == "ssm":
-        s = cfg.ssm
-        H = cfg.d_model // s.head_dim
-        L = cfg.num_layers
-        c["ssm_state"] = jnp.zeros((L, batch, H, s.head_dim, s.head_dim), jnp.float32)
-        c["shift_tm"] = jnp.zeros((L, batch, cfg.d_model), dtype_of(cfg.dtype))
-        c["shift_cm"] = jnp.zeros_like(c["shift_tm"])
-        return c
-    if cfg.family == "hybrid":
-        s = cfg.ssm
-        d_in, heads, conv_ch = mam.mamba_dims(cfg)
-        G = cfg.num_layers // cfg.attn_every
-        per = cfg.attn_every
-        c["ssm_state"] = jnp.zeros(
-            (G, per, batch, heads, s.head_dim, s.state_dim), jnp.float32
-        )
-        c["conv"] = jnp.zeros(
-            (G, per, batch, s.conv_dim - 1, conv_ch), dtype_of(cfg.dtype)
-        )
-        c["kv_k"] = jnp.zeros((G, batch, max_len, cfg.num_kv_heads, cfg.head_dim), kv_dt)
-        c["kv_v"] = jnp.zeros_like(c["kv_k"])
-        return c
-    L = cfg.num_layers
-    c["kv_k"] = jnp.zeros((L, batch, max_len, cfg.num_kv_heads, cfg.head_dim), kv_dt)
-    c["kv_v"] = jnp.zeros_like(c["kv_k"])
+    c.update(_family(cfg).cache(cfg, batch, max_len, enc_len, dtype_of(cfg.dtype)))
     return c
 
 
-def expert_tokens_shape(cfg) -> Optional[Tuple[int, int]]:
+def _kv_cache(cfg, layers: int, batch: int, max_len: int, dt) -> Cache:
+    k = jnp.zeros((layers, batch, max_len, cfg.num_kv_heads, cfg.head_dim), dt)
+    return {"kv_k": k, "kv_v": jnp.zeros_like(k)}
+
+
+def _dense_cache(cfg, batch, max_len, enc_len, dt) -> Cache:
+    return _kv_cache(cfg, cfg.num_layers, batch, max_len, dt)
+
+
+def _mla_cache(cfg, batch, max_len, enc_len, dt) -> Cache:
+    L = cfg.num_layers
+    return {
+        "kv_latent": jnp.zeros((L, batch, max_len, cfg.mla.cache_dim), dt),
+        EXPERT_TOKENS: jnp.zeros((L - _mla_dense_layers(cfg), cfg.moe.num_experts), jnp.int32),
+    }
+
+
+def _rwkv_cache(cfg, batch, max_len, enc_len, dt) -> Cache:
+    s, L = cfg.ssm, cfg.num_layers
+    shift = jnp.zeros((L, batch, cfg.d_model), dt)
+    return {
+        "ssm_state": jnp.zeros(
+            (L, batch, cfg.d_model // s.head_dim, s.head_dim, s.head_dim), jnp.float32),
+        "shift_tm": shift,
+        "shift_cm": jnp.zeros_like(shift),
+    }
+
+
+def _hybrid_cache(cfg, batch, max_len, enc_len, dt) -> Cache:
+    s = cfg.ssm
+    _, heads, conv_ch = mam.mamba_dims(cfg)
+    G, per = cfg.num_layers // cfg.attn_every, cfg.attn_every
+    return {
+        "ssm_state": jnp.zeros((G, per, batch, heads, s.head_dim, s.state_dim), jnp.float32),
+        "conv": jnp.zeros((G, per, batch, s.conv_dim - 1, conv_ch), dt),
+        **_kv_cache(cfg, G, batch, max_len, dt),
+    }
+
+
+def _whisper_cache(cfg, batch, max_len, enc_len, dt) -> Cache:
+    L, T = cfg.num_layers, enc_len or cfg.encoder.num_frames
+    cross = jnp.zeros((L, batch, T, cfg.num_kv_heads, cfg.head_dim), dt)
+    return {**_kv_cache(cfg, L, batch, max_len, dt),
+            "cross_k": cross, "cross_v": jnp.zeros_like(cross)}
+
+
+def _filled_cache(cfg, batch: int, cache_len: Optional[int], length: int,
+                  rows: Cache, *, enc_len: int = 0) -> Cache:
+    """``init_cache(cfg, batch, cache_len or length)`` with ``rows`` (every
+    key but ``length``) written in, in the layout's dtypes: a K/V or latent
+    array fills positions [0, length), a state the whole array, and the
+    rest stays zero. Each cache byte is written once."""
+    layout = abstract_cache(cfg, batch, cache_len or length, enc_len=enc_len)
+    cache: Cache = {"length": jnp.asarray(length, jnp.int32)}
+    for k, want in layout.items():
+        if k != "length":
+            v = rows[k]
+            cache[k] = jnp.pad(v.astype(want.dtype),
+                               [(0, n - m) for n, m in zip(want.shape, v.shape)])
+    return cache
+
+
+def expert_tokens_shape(cfg) -> Optional[Tuple[int, ...]]:
     """(MoE layers, experts) of the cache's ``EXPERT_TOKENS``, or None for
     a model whose cache carries none. Only MLA (DeepSeek-V2) stacks count
     their routed pairs: the other MoE paths (``moe_forward``) return none."""
-    if cfg.mla is None:
-        return None
-    return cfg.num_layers - _mla_dense_layers(cfg), cfg.moe.num_experts
+    routed = abstract_cache(cfg, 1, 1).get(EXPERT_TOKENS)
+    return None if routed is None else routed.shape
 
 
 def abstract_cache(cfg, batch: int, max_len: int, **kw) -> Cache:
     return jax.eval_shape(lambda: init_cache(cfg, batch, max_len, **kw))
+
+
+def prefix_refusal(cfg) -> Optional[str]:
+    """Why a stored per-head K/V prefix (``serving/kv_cache.py``'s page
+    pool) cannot be re-entered into this model's cache, or None where it
+    can: only a cache of nothing but per-head K/V takes one."""
+    return _family(cfg).no_prefix
 
 
 # ===========================================================================
@@ -327,7 +335,7 @@ def _embed(cfg, params: Params, batch: Batch, ctx) -> Tuple[jnp.ndarray, jnp.nda
         x = jnp.take(params["embed"], tokens, axis=0).astype(dtype_of(cfg.dtype))
         B, S = tokens.shape
         pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-    return x, pos
+    return _constrain(x, ctx, _bspec(ctx)), pos
 
 
 def _logits(cfg, params: Params, x: jnp.ndarray) -> jnp.ndarray:
@@ -336,15 +344,19 @@ def _logits(cfg, params: Params, x: jnp.ndarray) -> jnp.ndarray:
     return x @ w
 
 
-def _dense_layer_fwd(cfg, p, x, cos, sin, ctx, want_cache):
-    h, kv = attn.attention_seq(cfg, p["attn"], apply_norm(cfg, p, "ln1", x), cos, sin)
-    x = x + h
+def _ffn(cfg, p, x, ctx):
+    """A layer's MoE or MLP block on ``x``, residual added: (x, aux loss)."""
+    h = apply_norm(cfg, p, "ln2", x)
     if cfg.moe is not None:
-        m, aux = moe_mod.moe_forward(cfg, p["moe"], apply_norm(cfg, p, "ln2", x), ctx)
+        m, aux = moe_mod.moe_forward(cfg, p["moe"], h, ctx)
     else:
-        m = mlp_forward(cfg, p["mlp"], apply_norm(cfg, p, "ln2", x))
-        aux = jnp.zeros((), jnp.float32)
-    x = x + m
+        m, aux = mlp_forward(cfg, p["mlp"], h), jnp.zeros((), jnp.float32)
+    return x + m, aux
+
+
+def _dense_layer_fwd(cfg, p, x, cos, sin, ctx):
+    h, kv = attn.attention_seq(cfg, p["attn"], apply_norm(cfg, p, "ln1", x), cos, sin)
+    x, aux = _ffn(cfg, p, x + h, ctx)
     return x, aux, kv
 
 
@@ -362,57 +374,19 @@ def forward(
     Returns (logits (B,S,V), aux_loss, cache-or-None). When ``want_cache``,
     the cache covers ``cache_len`` positions (default S) with S filled.
     """
-    if cfg.family == "audio":
-        return _whisper_forward(cfg, params, batch, ctx, want_cache, cache_len)
+    return _family(cfg).forward(cfg, params, batch, ctx, want_cache, cache_len)
+
+
+def _dense_forward(cfg, params, batch, ctx, want_cache, cache_len):
+    """dense / moe / vlm"""
     x, pos = _embed(cfg, params, batch, ctx)
     B, S, _ = x.shape
-    bspec = None if ctx is None else P(ctx.batch_spec, ctx.seq_spec, None)
-    x = _constrain(x, ctx, bspec)
-    if cfg.mla is not None:
-        return _mla_forward(cfg, params, x, pos, ctx, want_cache, cache_len)
+    bspec = _bspec(ctx)
     cos, sin = positions_for_rope(cfg, pos, cfg.head_dim)
 
-    if cfg.family == "ssm":
-        x = apply_norm(cfg, params, "ln0", x)
-        state0 = jnp.zeros(
-            (B, cfg.d_model // cfg.ssm.head_dim, cfg.ssm.head_dim, cfg.ssm.head_dim),
-            jnp.float32,
-        )
-
-        def body(carry, p):
-            xc = carry
-            y, st, sh_tm = rwkv_mod.rwkv_time_mix(
-                cfg, p["rwkv"], apply_norm(cfg, p, "ln1", xc), state0, None
-            )
-            xc = xc + y
-            y2, sh_cm = rwkv_mod.rwkv_channel_mix(
-                cfg, p["rwkv"], apply_norm(cfg, p, "ln2", xc), None
-            )
-            xc = xc + y2
-            xc = _constrain(xc, ctx, bspec)
-            out = (st, sh_tm, sh_cm) if want_cache else None
-            return xc, out
-
-        x, outs = jax.lax.scan(_maybe_remat(body, ctx), x, params["blocks"])
-        logits = _logits(cfg, params, x)
-        cache = None
-        if want_cache:
-            st, sh_tm, sh_cm = outs
-            cache = {
-                "length": jnp.asarray(S, jnp.int32),
-                "ssm_state": st,
-                "shift_tm": sh_tm,
-                "shift_cm": sh_cm,
-            }
-        return logits, jnp.zeros((), jnp.float32), cache
-
-    if cfg.family == "hybrid":
-        return _hybrid_forward(cfg, params, x, cos, sin, ctx, want_cache, cache_len, S)
-
-    # dense / moe / vlm
     def body(carry, p):
         xc, aux = carry
-        xo, a, kv = _dense_layer_fwd(cfg, p, xc, cos, sin, ctx, want_cache)
+        xo, a, kv = _dense_layer_fwd(cfg, p, xc, cos, sin, ctx)
         xo = _constrain(xo, ctx, bspec)
         return (xo, aux + a), (kv if want_cache else None)
 
@@ -423,20 +397,42 @@ def forward(
     cache = None
     if want_cache:
         k_all, v_all = kvs  # (L, B, S, Hkv, hd)
-        M = cache_len or S
-        kv_dt = jnp.bfloat16 if cfg.dtype == "bfloat16" else x.dtype
-        if M > S:
-            padk = jnp.zeros(
-                (cfg.num_layers, B, M - S, cfg.num_kv_heads, cfg.head_dim), kv_dt
-            )
-            k_all = jnp.concatenate([k_all.astype(kv_dt), padk], axis=2)
-            v_all = jnp.concatenate([v_all.astype(kv_dt), padk], axis=2)
-        cache = {
-            "length": jnp.asarray(S, jnp.int32),
-            "kv_k": k_all.astype(kv_dt),
-            "kv_v": v_all.astype(kv_dt),
-        }
+        cache = _filled_cache(cfg, B, cache_len, S, {"kv_k": k_all, "kv_v": v_all})
     return logits, aux, cache
+
+
+def _rwkv_forward(cfg, params, batch, ctx, want_cache, cache_len):
+    x, _ = _embed(cfg, params, batch, ctx)
+    B, S, _ = x.shape
+    bspec = _bspec(ctx)
+    x = apply_norm(cfg, params, "ln0", x)
+    state0 = jnp.zeros(
+        (B, cfg.d_model // cfg.ssm.head_dim, cfg.ssm.head_dim, cfg.ssm.head_dim),
+        jnp.float32,
+    )
+
+    def body(carry, p):
+        xc = carry
+        y, st, sh_tm = rwkv_mod.rwkv_time_mix(
+            cfg, p["rwkv"], apply_norm(cfg, p, "ln1", xc), state0, None
+        )
+        xc = xc + y
+        y2, sh_cm = rwkv_mod.rwkv_channel_mix(
+            cfg, p["rwkv"], apply_norm(cfg, p, "ln2", xc), None
+        )
+        xc = xc + y2
+        xc = _constrain(xc, ctx, bspec)
+        out = (st, sh_tm, sh_cm) if want_cache else None
+        return xc, out
+
+    x, outs = jax.lax.scan(_maybe_remat(body, ctx), x, params["blocks"])
+    logits = _logits(cfg, params, x)
+    cache = None
+    if want_cache:
+        st, sh_tm, sh_cm = outs
+        cache = _filled_cache(cfg, B, cache_len, S,
+                              {"ssm_state": st, "shift_tm": sh_tm, "shift_cm": sh_cm})
+    return logits, jnp.zeros((), jnp.float32), cache
 
 
 def _mla_angles(cfg, pos):
@@ -453,9 +449,10 @@ def _split_experts(blocks):
     return {**blocks, "moe": rest}, experts
 
 
-def _mla_forward(cfg, params, x, pos, ctx, want_cache, cache_len):
+def _mla_forward(cfg, params, batch, ctx, want_cache, cache_len):
+    x, pos = _embed(cfg, params, batch, ctx)
     B, S, _ = x.shape
-    bspec = None if ctx is None else P(ctx.batch_spec, ctx.seq_spec, None)
+    bspec = _bspec(ctx)
     cos, sin = _mla_angles(cfg, pos)
 
     def attend(p, xc):
@@ -487,21 +484,20 @@ def _mla_forward(cfg, params, x, pos, ctx, want_cache, cache_len):
     logits = _logits(cfg, params, x)
     cache = None
     if want_cache:
-        kv_dt = jnp.bfloat16 if cfg.dtype == "bfloat16" else x.dtype
-        lat = jnp.concatenate(rows, axis=0).astype(kv_dt)  # (L, B, S, C)
-        M = cache_len or S
-        if M > S:
-            lat = jnp.pad(lat, ((0, 0), (0, 0), (0, M - S), (0, 0)))
-        cache = {"length": jnp.asarray(S, jnp.int32), "kv_latent": lat,
-                 EXPERT_TOKENS: counts}
+        cache = _filled_cache(cfg, B, cache_len, S, {
+            "kv_latent": jnp.concatenate(rows, axis=0),  # (L, B, S, C)
+            EXPERT_TOKENS: counts,
+        })
     return logits, aux, cache
 
 
-def _hybrid_forward(cfg, params, x, cos, sin, ctx, want_cache, cache_len, S):
-    B = x.shape[0]
+def _hybrid_forward(cfg, params, batch, ctx, want_cache, cache_len):
+    x, pos = _embed(cfg, params, batch, ctx)
+    B, S, _ = x.shape
     s = cfg.ssm
-    d_in, heads, conv_ch = mam.mamba_dims(cfg)
-    bspec = None if ctx is None else P(ctx.batch_spec, ctx.seq_spec, None)
+    _, heads, _ = mam.mamba_dims(cfg)
+    bspec = _bspec(ctx)
+    cos, sin = positions_for_rope(cfg, pos, cfg.head_dim)
     shared = params["shared_attn"]
     state0 = jnp.zeros((B, heads, s.head_dim, s.state_dim), jnp.float32)
 
@@ -517,7 +513,7 @@ def _hybrid_forward(cfg, params, x, cos, sin, ctx, want_cache, cache_len, S):
             xc = xc + y
             states.append(st)
             convs.append(cv)
-        xo, a, kv = _dense_layer_fwd(cfg, shared, xc, cos, sin, ctx, True)
+        xo, a, kv = _dense_layer_fwd(cfg, shared, xc, cos, sin, ctx)
         xo = _constrain(xo, ctx, bspec)
         out = None
         if want_cache:
@@ -531,20 +527,12 @@ def _hybrid_forward(cfg, params, x, cos, sin, ctx, want_cache, cache_len, S):
     cache = None
     if want_cache:
         st, cv, (k_all, v_all) = outs
-        M = cache_len or S
-        kv_dt = jnp.bfloat16 if cfg.dtype == "bfloat16" else x.dtype
-        if M > S:
-            G = cfg.num_layers // cfg.attn_every
-            padk = jnp.zeros((G, B, M - S, cfg.num_kv_heads, cfg.head_dim), kv_dt)
-            k_all = jnp.concatenate([k_all.astype(kv_dt), padk], axis=2)
-            v_all = jnp.concatenate([v_all.astype(kv_dt), padk], axis=2)
-        cache = {
-            "length": jnp.asarray(S, jnp.int32),
+        cache = _filled_cache(cfg, B, cache_len, S, {
             "ssm_state": st,
             "conv": cv[:, :, :, -(s.conv_dim - 1) :, :],
-            "kv_k": k_all.astype(kv_dt),
-            "kv_v": v_all.astype(kv_dt),
-        }
+            "kv_k": k_all,
+            "kv_v": v_all,
+        })
     return logits, aux, cache
 
 
@@ -595,21 +583,9 @@ def _whisper_forward(cfg, params, batch, ctx, want_cache, cache_len):
     cache = None
     if want_cache:
         (k_all, v_all), (ck_all, cv_all) = outs
-        M = cache_len or S
-        kv_dt = jnp.bfloat16 if cfg.dtype == "bfloat16" else xd.dtype
-        if M > S:
-            padk = jnp.zeros(
-                (cfg.num_layers, B, M - S, cfg.num_kv_heads, cfg.head_dim), kv_dt
-            )
-            k_all = jnp.concatenate([k_all.astype(kv_dt), padk], axis=2)
-            v_all = jnp.concatenate([v_all.astype(kv_dt), padk], axis=2)
-        cache = {
-            "length": jnp.asarray(S, jnp.int32),
-            "kv_k": k_all.astype(kv_dt),
-            "kv_v": v_all.astype(kv_dt),
-            "cross_k": ck_all.astype(kv_dt),
-            "cross_v": cv_all.astype(kv_dt),
-        }
+        cache = _filled_cache(cfg, B, cache_len, S, {
+            "kv_k": k_all, "kv_v": v_all, "cross_k": ck_all, "cross_v": cv_all,
+        }, enc_len=T)
     return logits, jnp.zeros((), jnp.float32), cache
 
 
@@ -640,26 +616,19 @@ def prefill_extend(
 
     prefix_k/v: (L, B, Sp, Hkv, hd) post-RoPE (Sp >= prefix_len; the
     overhang is page padding). prefix_len must be static under jit.
-    KV-recurrent families keep per-token state, so a stored prefix can't
-    be re-entered mid-stream — dense / moe / vlm only.
+    Only a cache of nothing but per-head K/V takes a stored prefix
+    (``prefix_refusal``); any other raises ``NotImplementedError``.
     """
-    if cfg.family in ("audio", "ssm", "hybrid"):
-        raise NotImplementedError(
-            f"prefix extension requires a pure-KV cache; family "
-            f"{cfg.family!r} carries recurrent state"
-        )
-    if cfg.mla is not None:
-        raise NotImplementedError(
-            f"prefix extension requires per-head K/V; {cfg.name!r} caches a "
-            f"latent per position"
-        )
+    refusal = prefix_refusal(cfg)
+    if refusal is not None:
+        raise NotImplementedError(f"prefix extension of {cfg.name}: {refusal}")
     tokens = batch["tokens"]
     B, S = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0).astype(dtype_of(cfg.dtype))
     pos = jnp.broadcast_to(
         prefix_len + jnp.arange(S, dtype=jnp.int32)[None], (B, S)
     )
-    bspec = None if ctx is None else P(ctx.batch_spec, ctx.seq_spec, None)
+    bspec = _bspec(ctx)
     x = _constrain(x, ctx, bspec)
     cos, sin = positions_for_rope(cfg, pos, cfg.head_dim)
 
@@ -670,15 +639,8 @@ def prefill_extend(
             cfg, p["attn"], apply_norm(cfg, p, "ln1", xc), cos, sin,
             pk, pv, prefix_len,
         )
-        xc = xc + h
-        if cfg.moe is not None:
-            m, a = moe_mod.moe_forward(cfg, p["moe"], apply_norm(cfg, p, "ln2", xc), ctx)
-        else:
-            m = mlp_forward(cfg, p["mlp"], apply_norm(cfg, p, "ln2", xc))
-            a = jnp.zeros((), jnp.float32)
-        xc = xc + m
-        xc = _constrain(xc, ctx, bspec)
-        return (xc, aux + a), kv
+        xc, a = _ffn(cfg, p, xc + h, ctx)
+        return (_constrain(xc, ctx, bspec), aux + a), kv
 
     (x, _), kvs = jax.lax.scan(
         body, (x, jnp.zeros((), jnp.float32)),
@@ -686,25 +648,12 @@ def prefill_extend(
     )
     logits = _logits(cfg, params, x)
     k_suf, v_suf = kvs  # (L, B, S, Hkv, hd)
-    total = prefix_len + S
-    M = cache_len or total
-    assert M >= total, (M, total)
-    kv_dt = jnp.bfloat16 if cfg.dtype == "bfloat16" else x.dtype
 
-    def assemble(pre, suf):
-        parts = [pre[:, :, :prefix_len].astype(kv_dt), suf.astype(kv_dt)]
-        if M > total:
-            parts.append(jnp.zeros(
-                (cfg.num_layers, B, M - total, cfg.num_kv_heads, cfg.head_dim),
-                kv_dt,
-            ))
-        return jnp.concatenate(parts, axis=2)
+    def rows(pre, suf):
+        return jnp.concatenate([pre[:, :, :prefix_len].astype(suf.dtype), suf], axis=2)
 
-    cache = {
-        "length": jnp.asarray(total, jnp.int32),
-        "kv_k": assemble(prefix_k, k_suf),
-        "kv_v": assemble(prefix_v, v_suf),
-    }
+    cache = _filled_cache(cfg, B, cache_len, prefix_len + S,
+                          {"kv_k": rows(prefix_k, k_suf), "kv_v": rows(prefix_v, v_suf)})
     return logits, cache
 
 
@@ -726,41 +675,12 @@ def decode_step(
     B = tokens.shape[0]
     x = jnp.take(params["embed"], tokens, axis=0).astype(dtype_of(cfg.dtype))
     pos = jnp.broadcast_to(length[None, None], (B, 1)).astype(jnp.int32)
-    if cfg.mla is not None:
-        return _mla_decode(cfg, params, cache, x, pos)
+    return _family(cfg).decode(cfg, params, cache, x, pos, ctx)
+
+
+def _dense_decode(cfg, params, cache, x, pos, ctx):
+    length = cache["length"]
     cos, sin = positions_for_rope(cfg, pos, cfg.head_dim)
-
-    if cfg.family == "ssm":
-        x2 = apply_norm(cfg, params, "ln0", x)[:, 0]  # (B, D)
-
-        def body(carry, inp):
-            xc = carry
-            p, st, sh_tm, sh_cm = inp
-            xn = apply_norm(cfg, p, "ln1", xc)
-            y, st, sh_tm = rwkv_mod.rwkv_time_mix_step(cfg, p["rwkv"], xn, st, sh_tm)
-            xc = xc + y
-            xn = apply_norm(cfg, p, "ln2", xc)
-            y2, sh_cm = rwkv_mod.rwkv_channel_mix(cfg, p["rwkv"], xn, sh_cm)
-            xc = xc + y2
-            return xc, (st, sh_tm, sh_cm)
-
-        x2, (st, sh_tm, sh_cm) = jax.lax.scan(
-            body, x2, (params["blocks"], cache["ssm_state"], cache["shift_tm"], cache["shift_cm"])
-        )
-        logits = _logits(cfg, params, x2[:, None])
-        new_cache = {
-            "length": length + 1,
-            "ssm_state": st,
-            "shift_tm": sh_tm,
-            "shift_cm": sh_cm,
-        }
-        return logits, new_cache
-
-    if cfg.family == "hybrid":
-        return _hybrid_decode(cfg, params, cache, x, cos, sin, ctx)
-
-    if cfg.family == "audio":
-        return _whisper_decode(cfg, params, cache, x, ctx)
 
     def body(carry, inp):
         xc, ck, cv = carry
@@ -768,12 +688,7 @@ def decode_step(
         h, ck, cv = attn.attention_decode(
             cfg, p["attn"], apply_norm(cfg, p, "ln1", xc), cos, sin, ck, cv, layer, length
         )
-        xc = xc + h
-        if cfg.moe is not None:
-            m, _ = moe_mod.moe_forward(cfg, p["moe"], apply_norm(cfg, p, "ln2", xc), ctx)
-        else:
-            m = mlp_forward(cfg, p["mlp"], apply_norm(cfg, p, "ln2", xc))
-        xc = xc + m
+        xc, _ = _ffn(cfg, p, xc + h, ctx)
         return (xc, ck, cv), None
 
     (x, ck, cv), _ = jax.lax.scan(
@@ -784,9 +699,35 @@ def decode_step(
     return logits, {"length": length + 1, "kv_k": ck, "kv_v": cv}
 
 
-def _mla_decode(cfg, params, cache, x, pos):
+def _rwkv_decode(cfg, params, cache, x, pos, ctx):
+    x2 = apply_norm(cfg, params, "ln0", x)[:, 0]  # (B, D)
+
+    def body(carry, inp):
+        xc = carry
+        p, st, sh_tm, sh_cm = inp
+        xn = apply_norm(cfg, p, "ln1", xc)
+        y, st, sh_tm = rwkv_mod.rwkv_time_mix_step(cfg, p["rwkv"], xn, st, sh_tm)
+        xc = xc + y
+        xn = apply_norm(cfg, p, "ln2", xc)
+        y2, sh_cm = rwkv_mod.rwkv_channel_mix(cfg, p["rwkv"], xn, sh_cm)
+        xc = xc + y2
+        return xc, (st, sh_tm, sh_cm)
+
+    x2, (st, sh_tm, sh_cm) = jax.lax.scan(
+        body, x2, (params["blocks"], cache["ssm_state"], cache["shift_tm"], cache["shift_cm"])
+    )
+    logits = _logits(cfg, params, x2[:, None])
+    return logits, {
+        "length": cache["length"] + 1,
+        "ssm_state": st,
+        "shift_tm": sh_tm,
+        "shift_cm": sh_cm,
+    }
+
+
+def _mla_decode(cfg, params, cache, x, pos, ctx):
     """Decode through the dense then MoE layer scans; the latent cache
-    rides their carry as the dense K/V cache rides ``decode_step``'s."""
+    rides their carry as the dense K/V cache rides ``_dense_decode``'s."""
     length = cache["length"]
     cos, sin = _mla_angles(cfg, pos)
     k = _mla_dense_layers(cfg)
@@ -821,8 +762,9 @@ def _mla_decode(cfg, params, cache, x, pos):
                     EXPERT_TOKENS: cache[EXPERT_TOKENS] + counts}
 
 
-def _hybrid_decode(cfg, params, cache, x, cos, sin, ctx):
+def _hybrid_decode(cfg, params, cache, x, pos, ctx):
     length = cache["length"]
+    cos, sin = positions_for_rope(cfg, pos, cfg.head_dim)
     shared = params["shared_attn"]
     x2 = x[:, 0]
 
@@ -844,8 +786,7 @@ def _hybrid_decode(cfg, params, cache, x, cos, sin, ctx):
             cfg, shared["attn"], apply_norm(cfg, shared, "ln1", x3), cos, sin, ck, cvv,
             group, length,
         )
-        x3 = x3 + h
-        x3 = x3 + mlp_forward(cfg, shared["mlp"], apply_norm(cfg, shared, "ln2", x3))
+        x3, _ = _ffn(cfg, shared, x3 + h, ctx)
         return (x3[:, 0], ck, cvv), (jnp.stack(sts), jnp.stack(cvs))
 
     (x2, ck, cvv), (st, cv) = jax.lax.scan(
@@ -864,7 +805,7 @@ def _hybrid_decode(cfg, params, cache, x, cos, sin, ctx):
     }
 
 
-def _whisper_decode(cfg, params, cache, x, ctx):
+def _whisper_decode(cfg, params, cache, x, pos, ctx):
     length = cache["length"]
     pos_emb = jax.lax.dynamic_index_in_dim(params["dec_pos"], length, keepdims=True)
     x = x + pos_emb[None].astype(x.dtype)
@@ -900,3 +841,44 @@ def _whisper_decode(cfg, params, cache, x, ctx):
         "cross_k": cache["cross_k"],
         "cross_v": cache["cross_v"],
     }
+
+
+# ===========================================================================
+# Families
+# ===========================================================================
+
+
+class _Family(NamedTuple):
+    """One family's implementation of each entry point of this module."""
+
+    params: Callable  # (cfg, keys, dtype) -> the stacks beside embed/final/head
+    cache: Callable  # (cfg, batch, max_len, enc_len, dtype) -> cache arrays
+    forward: Callable  # (cfg, params, batch, ctx, want_cache, cache_len)
+    decode: Callable  # (cfg, params, cache, x, pos, ctx) -> (logits, cache)
+    no_prefix: Optional[str]  # why a stored K/V prefix cannot enter the cache
+
+
+_DENSE = _Family(_dense_params, _dense_cache, _dense_forward, _dense_decode, None)
+_FAMILIES: Dict[str, _Family] = {
+    "dense": _DENSE,
+    "moe": _DENSE,
+    "vlm": _DENSE,
+    "mla": _Family(_mla_params, _mla_cache, _mla_forward, _mla_decode,
+                   "a latent (MLA) cache holds kv_latent, where the page pool "
+                   "holds per-head kv_k/kv_v"),
+    "ssm": _Family(_rwkv_params, _rwkv_cache, _rwkv_forward, _rwkv_decode,
+                   "an RWKV cache carries recurrent state, which a stored "
+                   "prefix cannot be re-entered into mid-stream"),
+    "hybrid": _Family(_hybrid_params, _hybrid_cache, _hybrid_forward, _hybrid_decode,
+                      "a hybrid (Mamba) cache carries recurrent state, which a "
+                      "stored prefix cannot be re-entered into mid-stream"),
+    "audio": _Family(_whisper_params, _whisper_cache, _whisper_forward, _whisper_decode,
+                     "an encoder-decoder cache carries its encoder's "
+                     "cross-attention K/V"),
+}
+
+
+def _family(cfg) -> _Family:
+    """The one place a config's path is chosen: latent attention
+    (DeepSeek-V2) by ``cfg.mla``, every other stack by ``cfg.family``."""
+    return _FAMILIES["mla" if cfg.mla is not None else cfg.family]

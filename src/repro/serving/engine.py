@@ -43,10 +43,11 @@ per-step spans cover the host's dispatch of the step and of the sampler
 ``eos_id`` is given; the first step's span runs on to its logits being
 ready); they lie inside the interval ``decode_s`` sums.
 
-An engine computes where its parameters live: on the default device, for
-every served path today. ``mesh=`` threads a ``ParallelCtx`` through the
-model code, but no caller passes one, so a model must fit one device
-(``configs/apc_minion.ONE_CHIP`` does on a TPU v5e; ``DEFAULT`` does not).
+An engine's parameters and caches live on the default device, so a model
+must fit one device (``configs/apc_minion.ONE_CHIP`` does on a TPU v5e;
+``DEFAULT`` does not). A model whose cache cannot take a stored prefix
+(``lm.prefix_refusal``) is served without the page pool, and a call that
+asks for prefix reuse on it raises ``NotImplementedError``.
 """
 
 from __future__ import annotations
@@ -60,8 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ModelConfig, ShardingProfile
-from repro.distributed import sharding as shd
+from repro.configs.base import ModelConfig
 from repro.models import lm
 from repro.obs import MetricsRegistry, trace_span
 from repro.obs.names import (
@@ -74,10 +74,6 @@ from repro.obs.names import (
 )
 from repro.serving.kv_cache import CachePoint, KVPrefixCache, PagePoolExhausted
 from repro.serving.sampler import sample_token
-
-# families whose cache is pure KV (no recurrent state): the only ones a
-# stored prefix can be re-entered into mid-stream
-_PREFIX_FAMILIES = ("dense", "moe", "vlm")
 
 
 @dataclass
@@ -97,17 +93,13 @@ class Engine:
         cfg: ModelConfig,
         params: Any,
         *,
-        mesh=None,
-        profile: Optional[ShardingProfile] = None,
         max_len: int = 512,
-        donate_cache: bool = True,
         kv_prefix: Optional[KVPrefixCache] = None,
         obs: Optional[MetricsRegistry] = None,
         obs_labels: Optional[Dict[str, str]] = None,
     ):
         self.cfg = cfg
         self.params = params
-        self.mesh = mesh
         self.max_len = max_len
         self.stats = EngineStats()
         self.obs = obs if obs is not None else MetricsRegistry()
@@ -117,36 +109,25 @@ class Engine:
             [self.obs.counter(MOE_EXPERT_TOKENS, **(obs_labels or {}),
                               layer=str(l), expert=str(e)) for e in range(routed[1])]
             for l in range(routed[0])]
-        self.kv_prefix = kv_prefix if cfg.family in _PREFIX_FAMILIES else None
-        ctx = None
-        if mesh is not None:
-            profile = profile or ShardingProfile()
-            ctx = lm.ParallelCtx(
-                mesh=mesh,
-                dp_axes=shd.dp_axes_for_mesh(mesh),
-                tp_axis=profile.tp_axis,
-                ep_axis=profile.ep_axis,
-            )
-        self.ctx = ctx
+        self._prefix_refusal = lm.prefix_refusal(cfg)
+        self.kv_prefix = kv_prefix if self._prefix_refusal is None else None
 
         def prefill_fn(params, batch):
-            logits, cache = lm.prefill(cfg, params, batch, ctx, cache_len=max_len)
+            logits, cache = lm.prefill(cfg, params, batch, cache_len=max_len)
             return logits[:, -1], cache
 
         def decode_fn(params, cache, tokens):
-            logits, cache = lm.decode_step(cfg, params, cache, tokens, ctx)
+            logits, cache = lm.decode_step(cfg, params, cache, tokens)
             return logits[:, -1], cache
 
         def extend_fn(params, prefix_k, prefix_v, batch, *, prefix_len):
             logits, cache = lm.prefill_extend(
-                cfg, params, batch, prefix_k, prefix_v, prefix_len, ctx,
-                cache_len=max_len,
+                cfg, params, batch, prefix_k, prefix_v, prefix_len, cache_len=max_len
             )
             return logits[:, -1], cache
 
-        donate = (1,) if donate_cache else ()
         self._prefill = jax.jit(prefill_fn)
-        self._decode = jax.jit(decode_fn, donate_argnums=donate)
+        self._decode = jax.jit(decode_fn, donate_argnums=(1,))
         self._extend = jax.jit(extend_fn, static_argnames=("prefix_len",))
 
     # ------------------------------------------------------------------
@@ -207,8 +188,8 @@ class Engine:
         pool under ``template_id`` (batch row 0 — the template prefix is
         identical across rows by construction). Call right after the full
         prefill that built ``cache``, before decode donates its buffers."""
-        self._refuse_latent_prefix()
-        if self.kv_prefix is None or "kv_k" not in cache:
+        self._refuse_prefix()
+        if self.kv_prefix is None:
             return False
         k = cache["kv_k"][:, 0]  # (L, M, Hkv, hd)
         v = cache["kv_v"][:, 0]
@@ -236,18 +217,17 @@ class Engine:
         from it — so a mismatched entry (stale registration, re-tokenized
         template) is treated as a miss, never served.
         """
-        self._refuse_latent_prefix()
+        self._refuse_prefix()
         res = self._prefill_with_prefix_on_device(
             template_id, suffix_tokens, n_valid, expected_len)
         return None if res is None else (np.asarray(res[0]), res[1])
 
-    def _refuse_latent_prefix(self) -> None:
-        """The page pool holds per-head K/V; a latent cache has no place in
-        it yet, so prefix reuse is refused rather than skipped."""
-        if self.cfg.mla is not None:
+    def _refuse_prefix(self) -> None:
+        """A cache that cannot take a stored prefix refuses prefix reuse
+        rather than skipping it."""
+        if self._prefix_refusal is not None:
             raise NotImplementedError(
-                f"prefix reuse of a latent (MLA) cache is not implemented: "
-                f"{self.cfg.name} caches kv_latent, the page pool holds kv_k/kv_v")
+                f"prefix reuse on {self.cfg.name}: {self._prefix_refusal}")
 
     def _prefill_with_prefix_on_device(
         self, template_id: str, suffix_tokens: np.ndarray,
@@ -313,7 +293,7 @@ class Engine:
                 f"past the cache"
             )
         if cache_point is not None:
-            self._refuse_latent_prefix()
+            self._refuse_prefix()
         n_valid = None if prompt_lengths is None else int(np.sum(prompt_lengths))
         with trace_span(SPAN_ENGINE_GENERATE, arch=self.cfg.name, batch=B,
                         prompt_len=S, max_new=max_new) as sp:

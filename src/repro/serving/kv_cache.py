@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models import lm
 from repro.obs import MetricsRegistry
 from repro.obs import names as _names
 
@@ -148,12 +149,11 @@ class PagePool:
 
 def pool_for_config(cfg, *, num_pages: int = 64,
                     page_size: int = 16) -> PagePool:
-    """Size a pool to a model config (dense-family cache geometry)."""
-    dt = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.dtype(cfg.dtype)
-    return PagePool(
-        cfg.num_layers, num_pages, page_size, cfg.num_kv_heads, cfg.head_dim,
-        dtype=dt,
-    )
+    """Size a pool to a model config: the per-head K/V geometry and dtype
+    of its cache, as ``lm.init_cache`` lays it out."""
+    kv = lm.abstract_cache(cfg, 1, 1)["kv_k"]
+    layers, _, _, heads, head_dim = kv.shape
+    return PagePool(layers, num_pages, page_size, heads, head_dim, dtype=kv.dtype)
 
 
 @dataclass(frozen=True)

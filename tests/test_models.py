@@ -45,6 +45,34 @@ def test_forward_shapes_and_finite(arch, rng_key):
 
 
 @pytest.mark.parametrize("arch", ARCHS)
+def test_prefill_cache_has_the_init_cache_layout(arch):
+    """Prefill, and prefix extension where the cache takes a stored prefix,
+    return exactly init_cache's tree, shapes and dtypes: decode's donated
+    cache keeps one layout whichever program built it."""
+    cfg = registry.get_smoke(arch)
+    B, S, M, enc_len = 2, 6, 16, 0
+    params = lm.abstract_params(cfg)
+    batch = {"tokens": jnp.zeros((B, S), jnp.int32)}
+    if cfg.family == "audio":
+        enc_len = 12
+        batch["frames"] = jnp.zeros((B, enc_len, cfg.d_model), jnp.float32)
+
+    def layout(tree):
+        return jax.tree.map(lambda a: (a.shape, a.dtype), tree)
+
+    want = layout(lm.abstract_cache(cfg, B, M, enc_len=enc_len))
+    _, cache = jax.eval_shape(lambda p: lm.prefill(cfg, p, batch, cache_len=M), params)
+    assert layout(cache) == want
+    if lm.prefix_refusal(cfg) is None:
+        L, _, _, H, hd = want["kv_k"][0]
+        pk = jax.ShapeDtypeStruct((L, B, 8, H, hd), want["kv_k"][1])
+        _, cache = jax.eval_shape(
+            lambda p, k, v: lm.prefill_extend(cfg, p, batch, k, v, 5, cache_len=M),
+            params, pk, pk)
+        assert layout(cache) == want
+
+
+@pytest.mark.parametrize("arch", ARCHS)
 def test_prefill_decode_equivalence(arch, rng_key):
     """forward(S+1)[-1] == prefill(S) + decode_step(token S)."""
     cfg = _fp32(registry.get_smoke(arch))
@@ -139,10 +167,12 @@ def test_flash_vs_naive_attention_in_model(rng_key):
     cfg = dataclasses.replace(cfg, dtype="float32", param_dtype="float32")
     p = attn.attn_init(cfg, rng_key)
     x = jax.random.normal(jax.random.PRNGKey(5), (2, 640, cfg.d_model))
-    from repro.models.rope import positions_for_rope
+    from repro.models.rope import apply_rope, positions_for_rope
 
     pos = jnp.broadcast_to(jnp.arange(640, dtype=jnp.int32)[None], (2, 640))
     cos, sin = positions_for_rope(cfg, pos, cfg.head_dim)
-    o_flash, _ = attn.attention_seq(cfg, p, x, cos, sin, use_flash=True)
-    o_naive, _ = attn.attention_seq(cfg, p, x, cos, sin, use_flash=False)
+    q, k, v = attn._project_qkv(cfg, p, x)
+    q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+    o_flash = attn.flash_attention_jnp(q, k, v, chunk=cfg.attn_chunk)
+    o_naive = attn.naive_attention(q, k, v)
     assert np.abs(np.asarray(o_flash - o_naive, np.float32)).max() < 1e-3
